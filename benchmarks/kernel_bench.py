@@ -27,7 +27,8 @@ def run(results: Dict) -> List[tuple]:
     B, S, H, hd = 1, 256, 2, 64
     q = jnp.asarray(rng.standard_normal((B, S, H, hd)), jnp.float32)
     us = _timeit(lambda a: flash_attention(a, q, q, causal=True,
-                                           block_q=128, block_k=128), q)
+                                           block_q=128, block_k=128,
+                                           interpret=True), q)
     flops = 4 * B * H * S * S * hd
     # VMEM working set per grid step: q,k,v tiles + f32 scores + acc
     vmem = (128 * hd * 4 * 2 + 128 * hd * 4 + 128 * 128 * 4
@@ -38,10 +39,11 @@ def run(results: Dict) -> List[tuple]:
     from repro.kernels.paged_attention.ops import paged_decode_attention
     B, H, KV, hd, ps, npg, pool = 4, 8, 2, 64, 16, 8, 64
     q = jnp.asarray(rng.standard_normal((B, 1, H, hd)), jnp.float32)
-    kp = jnp.asarray(rng.standard_normal((pool, ps, KV, hd)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((pool, KV, ps, hd)), jnp.float32)
     bt = jnp.asarray(rng.integers(0, pool, (B, npg)), jnp.int32)
     ln = jnp.full((B,), npg * ps, jnp.int32)
-    us = _timeit(lambda a: paged_decode_attention(a, kp, kp, bt, ln), q)
+    us = _timeit(lambda a: paged_decode_attention(a, kp, kp, bt, ln,
+                                                  interpret=True), q)
     bytes_moved = 2 * npg * ps * KV * hd * 4 * B
     rows.append(("kernel.paged_decode", us,
                  f"kv_bytes={bytes_moved:.2e}|pages={npg}"))
@@ -52,7 +54,8 @@ def run(results: Dict) -> List[tuple]:
     dt = jnp.asarray(rng.random((b, l, h)) * .4 + .1, jnp.float32)
     A = -jnp.asarray(rng.random((h,)) + .5, jnp.float32)
     Bm = jnp.asarray(rng.standard_normal((b, l, g, n)) * .3, jnp.float32)
-    us = _timeit(lambda a: ssd(a, dt, A, Bm, Bm, chunk=chunk), x)
+    us = _timeit(lambda a: ssd(a, dt, A, Bm, Bm, chunk=chunk,
+                               interpret=True), x)
     flops = b * h * (l // chunk) * (2 * chunk * chunk * (n + p)
                                     + 2 * chunk * p * n * 2)
     rows.append(("kernel.ssd_256", us, f"flops={flops:.2e}|chunk={chunk}"))
@@ -61,7 +64,7 @@ def run(results: Dict) -> List[tuple]:
     meta = jnp.asarray(rng.integers(0, 64, (4096,)), jnp.int32)
     slots = jnp.asarray(rng.integers(0, 4096, (2048,)), jnp.int32)
     tags = jnp.asarray(rng.integers(0, 4, (2048,)), jnp.int32)
-    us = _timeit(lambda s: probe(meta, s, tags), slots)
+    us = _timeit(lambda s: probe(meta, s, tags, interpret=True), slots)
     rows.append(("kernel.amil_probe_2k", us,
                  "resolves=2048 blocks|table_KiB=16"))
 

@@ -45,7 +45,10 @@ def _install_sigterm() -> None:
 
 def main() -> int:
     from repro import obs
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.resilience import sweepckpt
+
+    enable_compile_cache()
 
     from . import figures, kernel_bench, roofline, scenarios
     from . import um as um_bench

@@ -21,6 +21,7 @@ from . import bypass as bp
 from . import ctc as ctc_mod
 from .timing import HMSConfig
 from .traces import Trace, preprocess
+from .x64 import x64_scoped
 
 _COUNTERS = (
     "demand_dram_rd", "demand_dram_wr", "demand_scm_rd", "demand_scm_wr",
@@ -232,6 +233,7 @@ def _build_step(cfg: HMSConfig, n_pages: int):
     return step
 
 
+@x64_scoped
 def reference_counters(trace: Trace, cfg: HMSConfig) -> Dict[str, float]:
     """Run the seed scan engine and return its counter dict."""
     cfg = cfg.validate()

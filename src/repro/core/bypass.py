@@ -22,27 +22,31 @@ import jax.numpy as jnp
 from .timing import DeviceTiming
 
 
-def scm_penalty_score(ncols, has_write, dram: DeviceTiming, scm: DeviceTiming):
+def scm_penalty_score(ncols, has_write, dram: DeviceTiming, scm: DeviceTiming,
+                      xp=jnp):
     """Eq. 1, using the static pre-computation of §III-C1.
 
     Because column-access latency is identical between SCM and DRAM, the
     numerator collapses to (tRCD_scm - tRCD_dram) for read-only activations
     plus (tWR_scm - tWR_dram) when the activation includes a write.
+
+    ``xp`` is the array module: ``jnp`` in the frozen reference scan,
+    ``numpy`` where the simulator evaluates the policy on the host.
     """
-    ncols = jnp.maximum(jnp.asarray(ncols, dtype=jnp.float32), 1.0)
-    num = (scm.rcd - dram.rcd) + jnp.asarray(has_write, jnp.float32) * (
+    ncols = xp.maximum(xp.asarray(ncols, dtype=xp.float32), 1.0)
+    num = (scm.rcd - dram.rcd) + xp.asarray(has_write, xp.float32) * (
         scm.wr - dram.wr
     )
     return num / ncols
 
 
-def discretize(score, max_seen, n_levels: int):
+def discretize(score, max_seen, n_levels: int, xp=jnp):
     """Discretize ``score`` into ``n_levels`` fixed intervals of [0, max]."""
-    max_seen = jnp.maximum(jnp.asarray(max_seen, jnp.float32), 1e-6)
-    lvl = jnp.floor(
-        jnp.asarray(score, jnp.float32) / max_seen * n_levels
-    ).astype(jnp.int32)
-    return jnp.clip(lvl, 0, n_levels - 1)
+    max_seen = xp.maximum(xp.asarray(max_seen, xp.float32), 1e-6)
+    lvl = xp.floor(
+        xp.asarray(score, xp.float32) / max_seen * n_levels
+    ).astype(xp.int32)
+    return xp.clip(lvl, 0, n_levels - 1)
 
 
 def ema_update(avg, value, weight: float):
@@ -50,20 +54,20 @@ def ema_update(avg, value, weight: float):
     return (1.0 - weight) * avg + weight * value
 
 
-def affinity_score(penalty, act_count, use_counter: bool):
+def affinity_score(penalty, act_count, use_counter: bool, xp=jnp):
     """DRAM-affinity score = SCM-penalty x per-page activation counter.
 
     §IV-A disables the counter "for simplicity" (constant 1); we keep both
     modes behind ``use_counter``.
     """
-    act = jnp.asarray(act_count, jnp.float32)
-    return penalty * jnp.where(use_counter, jnp.maximum(act, 1.0), 1.0)
+    act = xp.asarray(act_count, xp.float32)
+    return penalty * xp.where(use_counter, xp.maximum(act, 1.0), 1.0)
 
 
-def p_dec(act_count, max_act):
+def p_dec(act_count, max_act, xp=jnp):
     """Victim decay probability: page activations / max activations seen."""
-    max_act = jnp.maximum(jnp.asarray(max_act, jnp.float32), 1.0)
-    return jnp.clip(jnp.asarray(act_count, jnp.float32) / max_act, 0.0, 1.0)
+    max_act = xp.maximum(xp.asarray(max_act, xp.float32), 1.0)
+    return xp.clip(xp.asarray(act_count, xp.float32) / max_act, 0.0, 1.0)
 
 
 def xorshift32(state):

@@ -17,8 +17,10 @@ per §III of the paper:
 Runtime is a bottleneck (roofline-style) model: the max of channel-bus
 occupancy, per-rank bank occupancy (activation/recovery amortized over the
 MSHR run), host-link occupancy, serialized fault handling, and a compute
-floor.  Counters are float64 (x64 is enabled on import: traces are ~10^6
-requests and fp32 accumulators would lose increments).
+floor.  Counters are float64 (traces are ~10^6 requests and fp32
+accumulators would lose increments); the engine entry points trace, compile
+and run under ``jax.enable_x64(True)`` (the scan packs int64 words), so the
+rest of the process — the model stack, the kernels — stays 32-bit.
 
 Engine architecture (compile-once, batched, shard-parallel)
 -----------------------------------------------------------
@@ -30,16 +32,19 @@ split so a sweep costs one compile and one short device loop:
     geometry) — forms an ``_EngineKey`` into a module-level jit cache.
     Slot/set allocations are bucketed to powers of two so nearby footprints
     share a compiled engine.
-  * **Runtime scalars** — device timings, ``ema_weight``, ``n_levels``,
-    ``bear_fill_prob``, thresholds, enabled CTC ways/sets, tag-layout costs
-    — are traced arguments; sweeping them never re-traces.
-  * Everything per-request-pure is hoisted out of the sequential scan into
-    vectorized precompute: SCM penalty scores, the penalty EMA / running
-    maxima (tiny scalar scan + ``lax.cummax``), activation-counter values
-    (segmented prefix sums in ``preprocess``), the xorshift dice stream, and
-    per-column activation shares.  The scan carries only genuinely stateful
-    arrays (packed DRAM-cache words + CTC state) and emits per-step decision
-    flags from which all counters are reduced vectorially.
+  * **Runtime scalars** — enabled CTC ways/sets are traced arguments;
+    device timings, ``ema_weight``, ``n_levels``, ``bear_fill_prob``,
+    thresholds and tag-layout costs shape the host-side request stream and
+    counter reduction.  Sweeping any of them never re-traces.
+  * Everything per-request-pure is hoisted out of the sequential scan and
+    evaluated on the host in numpy: SCM penalty scores, the penalty EMA /
+    running maxima, activation-counter values (segmented prefix sums in
+    ``preprocess``), the xorshift dice test, and per-column activation
+    shares.  The device scan is integer-only: it carries the genuinely
+    stateful arrays (packed DRAM-cache words + CTC state) and emits one
+    packed decision word per request, from which the host reduces every
+    counter in float64.  Counters are therefore bit-identical on every
+    backend, including a TPU, whose float64 is emulated.
   * **Shard parallelism** — the carried state partitions by address: a
     cache slot belongs to exactly one row group, and a power-of-two shard
     factor S dividing the CTC set count makes ``row_group % S`` a function
@@ -88,15 +93,13 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import itertools
 import os
 import time
 import types
 from typing import Dict, List, Sequence
 
 import jax
-
-jax.config.update("jax_enable_x64", True)
-
 import jax.numpy as jnp
 import numpy as np
 
@@ -106,6 +109,7 @@ from . import bypass as bp
 from . import costmodel
 from . import ctc as ctc_mod
 from . import tsplit
+from .x64 import x64_scoped
 from .timing import (
     COLUMN_BYTES,
     COLUMNS_PER_ROW,
@@ -272,30 +276,25 @@ def _engine_key(trace: Trace, cfg: HMSConfig) -> _EngineKey:
 
 def _runtime_params(cfg: HMSConfig,
                     n_sets_local: int = -1) -> Dict[str, np.ndarray]:
-    """Everything the engine treats as data: sweeping these re-uses the
-    compiled scan.  Timing values are exact small integers, so f32 carries
-    them losslessly (matching the seed engine's weak-typed arithmetic).
-    ``n_sets_local`` is the *shard-local* CTC set count from the shard plan
-    (the sets of one config partition across its shards)."""
-    dram, scm = cfg.dram_timing, cfg.scm_timing
-    amil = cfg.tag_layout == "amil"
+    """The config scalars the device scan reads: sweeping these re-uses the
+    compiled scan.  ``n_sets_local`` is the *shard-local* CTC set count from
+    the shard plan (the sets of one config partition across its shards).
+    Every other config field acts on the host side
+    (:func:`_request_stream`, :func:`_reduce_counters`)."""
     return {
-        "dram_rcd": np.float32(dram.rcd), "dram_wr": np.float32(dram.wr),
-        "dram_rp": np.float32(dram.rp),
-        "scm_rcd": np.float32(scm.rcd), "scm_wr": np.float32(scm.wr),
-        "scm_rp": np.float32(scm.rp),
-        "ema_weight": np.float64(cfg.ema_weight),
-        "n_levels": np.int32(cfg.n_levels),
-        "use_act_counter": np.bool_(cfg.use_activation_counter),
-        "bear_fill_prob": np.float32(cfg.bear_fill_prob),
-        "redcache_threshold": np.int32(cfg.redcache_threshold),
         "ctc_ways": np.int32(cfg.ctc_ways),
         "ctc_sets": np.int32(cfg.ctc_sets if n_sets_local < 0
                              else n_sets_local),
-        "probe_cost": np.float32(1.0 if amil else float(cfg.lines_per_row)),
-        "meta_wr_cost": np.float32(1.0 if amil else 0.0),
-        "cpl": np.float32(cfg.columns_per_line),
     }
+
+
+def _timings(cfg: HMSConfig):
+    """DRAM / SCM timings as float32 scalars — the precision every policy
+    score and busy-cycle share is evaluated in (exact small integers)."""
+    def f32(t):
+        return types.SimpleNamespace(
+            rcd=np.float32(t.rcd), wr=np.float32(t.wr), rp=np.float32(t.rp))
+    return f32(cfg.dram_timing), f32(cfg.scm_timing)
 
 
 # ---------------------------------------------------------------------------
@@ -331,8 +330,198 @@ def _dice(n: int) -> np.ndarray:
     return _DICE_F32[n]
 
 
-def _engine_inputs(trace: Trace, cfg: HMSConfig, pre,
+# ---------------------------------------------------------------------------
+# Host side: the per-request-pure policy stream and the counter reduction.
+#
+# Only the stateful scan runs on the device, and it is integer-only: packed
+# slot / request words in, packed decision flags out.  Every float the model
+# computes — penalty and affinity scores, the penalty EMA and running
+# maxima, discretized levels, busy-cycle shares and the float64 counter
+# sums — is evaluated here in numpy, so counters are bit-identical on every
+# backend (a TPU has no float64 unit: XLA emulates it with float32 pairs,
+# which is not IEEE binary64).
+# ---------------------------------------------------------------------------
+
+def _request_stream(trace: Trace, cfg: HMSConfig,
+                    pre) -> Dict[str, np.ndarray]:
+    """The config's per-request policy inputs: the packed scan word minus
+    its shard-local row group (``meta``, int64; see :func:`_make_engine`
+    for the layout) plus the arrays :func:`_reduce_counters` reads."""
+    policy = cfg.policy
+    dram, scm = _timings(cfg)
+    ncols = pre["run_ncols"]
+    page_act = pre["page_act"]
+    is_write = pre["is_write"]
+    excluded = pre["amil_excluded"] & (cfg.tag_layout == "amil")
+    dice = _dice(trace.n)
+
+    pen = bp.scm_penalty_score(ncols, pre["run_haswrite"], dram, scm, xp=np)
+    pen64 = pen.astype(np.float64)
+    pen_max = np.maximum.accumulate(pen64)
+    # the EMA is a sequential float64 recurrence: evaluated in Python floats
+    # (IEEE binary64, same operation order as the reference scan)
+    w = float(cfg.ema_weight)
+    pen_ema = np.fromiter(
+        itertools.accumulate(pen64.tolist(),
+                             lambda a, v: bp.ema_update(a, v, w),
+                             initial=0.0),
+        np.float64, count=trace.n + 1)[1:]
+    lv = cfg.n_levels
+    req_lvl = bp.discretize(pen, pen_max, lv, xp=np)
+    avg_lvl = bp.discretize(pen_ema, pen_max, lv, xp=np)
+    aff = bp.affinity_score(pen, page_act, cfg.use_activation_counter, xp=np)
+    aff_max = np.maximum.accumulate(aff.astype(np.float64))
+    req_aff_lvl = bp.discretize(aff, aff_max, lv, xp=np)
+    pass1 = req_lvl > avg_lvl
+    dec_ok = dice < bp.p_dec(page_act, pre["max_act"], xp=np)
+
+    # fill candidacy before the (stateful) accept decision
+    if policy in ("hms", "no_second_level"):
+        cand = ~excluded & pass1
+    elif policy in ("no_bypass", "no_bypass_no_ctc", "always_cache"):
+        cand = ~excluded
+    elif policy == "bear":
+        cand = dice < np.float32(cfg.bear_fill_prob)
+    elif policy == "redcache":
+        cand = page_act >= np.int32(cfg.redcache_threshold)
+    elif policy == "mccache":
+        cand = ~is_write
+    else:
+        raise _rvalidate.unknown_policy_error(policy)
+
+    meta = (is_write.astype(np.int64)
+            | (dec_ok.astype(np.int64) << 1)
+            | (cand.astype(np.int64) << 2)
+            | (pre["sector"].astype(np.int64) << 3)
+            | (req_aff_lvl.astype(np.int64) << 8)
+            | (pre["tag"].astype(np.int64) << 40))
+    return {"meta": meta, "is_write": is_write, "excluded": excluded,
+            "pass1": pass1, "ncols": ncols}
+
+
+def _reduce_counters(trace: Trace, cfg: HMSConfig, rs: Dict[str, np.ndarray],
+                     y: np.ndarray) -> Dict[str, np.ndarray]:
+    """Counters from the scan's trace-order decision words ``y``.
+
+    Phased traces reduce every counter per phase (``(P,)`` float64); the
+    whole-trace totals are then *defined* as the sum of the per-phase
+    vector, so phase attribution is exact by construction.  Unphased traces
+    reduce to float64 scalars."""
+    policy = cfg.policy
+    use_ctc = policy in _USES_CTC
+    ideal_probe = policy in ("bear", "redcache", "mccache")
+    two_level = policy in ("hms", "no_second_level")
+    dram, scm = _timings(cfg)
+    amil = cfg.tag_layout == "amil"
+    probe_cost = np.float32(1.0 if amil else float(cfg.lines_per_row))
+    meta_wr_cost = np.float32(1.0 if amil else 0.0)
+    cpl = np.float32(cfg.columns_per_line)
+    ncols = rs["ncols"]
+    is_write = rs["is_write"]
+
+    hit = (y & 1) != 0
+    c_hit = (y & 2) != 0
+    do_fill = (y & 4) != 0
+    rejected = (y & 8) != 0
+    dec = (y & 16) != 0
+    wb = (y & 32) != 0
+    nar = (y & 64) != 0
+    miss = ~hit
+
+    n_ph = trace.n_phases
+    if n_ph > 1:
+        C = {k: np.zeros((n_ph,), np.float64) for k in _COUNTERS}
+
+        def add(name, v):
+            C[name] = C[name] + np.bincount(
+                trace.phase_id, weights=np.asarray(v, np.float64),
+                minlength=n_ph)
+    else:
+        C = {k: np.float64(0.0) for k in _COUNTERS}
+
+        def add(name, v):
+            C[name] = C[name] + np.sum(np.asarray(v, np.float64))
+
+    if use_ctc:
+        add("ctc_hit", c_hit)
+        add("ctc_miss", ~c_hit)
+        add("probe_cols", np.where(c_hit, 0.0, probe_cost))
+        add("dram_busy",
+            np.where(c_hit, 0.0, dram.rcd + probe_cost + dram.rp))
+        add("dram_acts", np.where(c_hit, 0.0, 1.0))
+    elif not ideal_probe:
+        add("ctc_miss", np.ones_like(hit))
+        add("probe_cols", np.full(hit.shape, probe_cost))
+        add("dram_busy",
+            np.full(hit.shape, dram.rcd + probe_cost + dram.rp))
+        add("dram_acts", np.ones_like(hit))
+
+    if two_level:
+        add("bypass_l1", miss & ~rs["excluded"] & ~rs["pass1"])
+        add("bypass_l2", rejected)
+        add("aff_decs", dec)
+        if policy == "hms":
+            add("probe_cols", nar)
+            add("dram_busy",
+                np.where(nar, dram.rcd + 1.0 + dram.rp, 0.0))
+            add("dram_acts", nar)
+
+    rd = ~is_write
+    add("hit_r", hit & rd)
+    add("hit_w", hit & is_write)
+    add("miss_r", miss & rd)
+    add("miss_w", miss & is_write)
+    add("demand_dram_rd", hit & rd)
+    add("demand_dram_wr", hit & is_write)
+    dram_share = (dram.rcd + dram.rp) / ncols + np.where(
+        is_write, dram.wr / ncols, 0.0)
+    scm_share = (scm.rcd + scm.rp) / ncols + np.where(
+        is_write, scm.wr / ncols, 0.0)
+    add("dram_busy", np.where(hit, 1.0 + dram_share, 0.0))
+    add("dram_acts", np.where(hit, 1.0 / ncols, 0.0))
+    if policy == "mccache":
+        wt = hit & is_write
+        add("demand_scm_wr", wt)
+        add("scm_busy", np.where(wt, 1.0 + scm_share, 0.0))
+        add("scm_acts", np.where(wt, 1.0 / ncols, 0.0))
+        add("scm_wr_acts", np.where(wt, 1.0 / ncols, 0.0))
+
+    dem_scm_rd = miss & rd & ~do_fill
+    dem_scm_wr = miss & is_write & ~do_fill
+    add("demand_scm_rd", dem_scm_rd)
+    add("demand_scm_wr", dem_scm_wr)
+    add("scm_busy",
+        np.where(dem_scm_rd | dem_scm_wr, 1.0 + scm_share, 0.0))
+    add("scm_acts", np.where(dem_scm_rd | dem_scm_wr, 1.0 / ncols, 0.0))
+    add("scm_wr_acts", np.where(dem_scm_wr, 1.0 / ncols, 0.0))
+
+    add("fills", do_fill)
+    add("fill_scm_rd", np.where(do_fill, cpl, 0.0))
+    add("fill_dram_wr", np.where(do_fill, cpl, 0.0))
+    add("meta_wr_cols", np.where(do_fill, meta_wr_cost, 0.0))
+    add("scm_busy", np.where(do_fill, scm.rcd + cpl + scm.rp, 0.0))
+    add("dram_busy",
+        np.where(do_fill, dram.rcd + cpl + dram.wr + dram.rp
+                 + meta_wr_cost, 0.0))
+    add("scm_acts", do_fill)
+    add("dram_acts", do_fill)
+
+    add("dirty_evicts", wb)
+    add("wb_dram_rd", np.where(wb, cpl, 0.0))
+    add("wb_scm_wr", np.where(wb, cpl, 0.0))
+    add("dram_busy", np.where(wb, dram.rcd + cpl + dram.rp, 0.0))
+    add("scm_busy", np.where(wb, scm.rcd + cpl + scm.wr + scm.rp, 0.0))
+    add("dram_acts", wb)
+    add("scm_acts", wb)
+    add("scm_wr_acts", wb)
+    return C
+
+
+def _engine_inputs(trace: Trace, cfg: HMSConfig, pre, rs,
                    key: _EngineKey) -> Dict[str, np.ndarray]:
+    """Device inputs of one config at ``key``'s shard plan: shard-local
+    slots, the packed request words (``rs["meta"]`` plus the shard-local
+    row group) and the gather/scatter positions."""
     # packed-word layout limits (tag<<10 must stay inside int32; affinity
     # levels live in an 8-bit field; CTC tag+1 in a 23-bit field) — raised
     # as structured EngineInvariantErrors so python -O keeps the guarantee
@@ -349,18 +538,7 @@ def _engine_inputs(trace: Trace, cfg: HMSConfig, pre,
         pos = np.concatenate([pos, pad], axis=1)
     out = {
         "slot": plan["slot_local"],
-        "tag": pre["tag"],
-        "is_write": pre["is_write"],
-        "row_group": plan["rg_local"],
-        "sector": pre["sector"],
-        "run_ncols": pre["run_ncols"],
-        "run_haswrite": pre["run_haswrite"],
-        "page_act": pre["page_act"],
-        "max_act": pre["max_act"],
-        # tag layout folds into per-request data + cost scalars, so AMIL vs
-        # TAD sweeps share one compile
-        "excluded": pre["amil_excluded"] & (cfg.tag_layout == "amil"),
-        "dice": _dice(trace.n),
+        "meta": rs["meta"] | (plan["rg_local"].astype(np.int64) << 17),
         "pos": pos,
     }
     if key.t_segments > 1:
@@ -373,79 +551,26 @@ def _engine_inputs(trace: Trace, cfg: HMSConfig, pre,
         if key.replay > 0:
             out["gpos"] = sp["gpos"].reshape(lanes, -1)
             out["replay"] = sp["replay"].reshape(lanes, -1)
-    if trace.n_phases > 1:
-        out["phase"] = trace.phase_id
     return out
 
 
 # ---------------------------------------------------------------------------
-# The compiled engine: vectorized precompute + lean scan + counter reduce.
+# The compiled engine: the lean, integer-only stateful scan.
 # ---------------------------------------------------------------------------
 
 def _make_engine(key: _EngineKey):
     policy = key.policy
     use_ctc = policy in _USES_CTC
     ideal_probe = policy in ("bear", "redcache", "mccache")
-    two_level = policy in ("hms", "no_second_level")
-    mc_wt = policy == "mccache"
-    dirty_ok = not mc_wt
+    dirty_ok = policy != "mccache"
     # Temporally split engines (T > 1) take explicit boundary carries and
-    # return the per-lane final carries alongside the counters, so the host
-    # stitch loop can compose and re-run them to the exact fixed point.
-    # Unsplit engines keep the lean (xs, p) -> C shape — no carry transfer
+    # return the per-lane final carries alongside the decision words, so the
+    # host stitch loop can compose and re-run them to the exact fixed point.
+    # Unsplit engines keep the lean (xs, p) -> y shape — no carry transfer
     # on the common path.
     split = key.t_segments > 1
 
     def _impl(xs, p, carry, use_replay):
-        ncols = jnp.asarray(xs["run_ncols"])
-        haswrite = jnp.asarray(xs["run_haswrite"])
-        is_write = jnp.asarray(xs["is_write"])
-        page_act = jnp.asarray(xs["page_act"])
-        max_act = jnp.asarray(xs["max_act"])
-        dice = jnp.asarray(xs["dice"])
-        excluded = jnp.asarray(xs["excluded"])
-
-        dram = types.SimpleNamespace(
-            rcd=p["dram_rcd"], wr=p["dram_wr"], rp=p["dram_rp"])
-        scm = types.SimpleNamespace(
-            rcd=p["scm_rcd"], wr=p["scm_wr"], rp=p["scm_rp"])
-
-        # ---- per-request-pure precompute (was scan-carried in the seed) ---
-        pen = bp.scm_penalty_score(ncols, haswrite, dram, scm)
-        pen64 = pen.astype(jnp.float64)
-        pen_max = jax.lax.cummax(pen64, axis=0)
-
-        def ema_step(avg, v):
-            nxt = bp.ema_update(avg, v, p["ema_weight"])
-            return nxt, nxt
-
-        # unroll: same sequential recurrence (bitwise-identical to the seed's
-        # in-scan EMA), just with 32x less while-loop overhead
-        _, pen_ema = jax.lax.scan(
-            ema_step, jnp.zeros((), jnp.float64), pen64, unroll=32)
-
-        req_lvl = bp.discretize(pen, pen_max, p["n_levels"])
-        avg_lvl = bp.discretize(pen_ema, pen_max, p["n_levels"])
-        aff = bp.affinity_score(pen, page_act, p["use_act_counter"])
-        aff_max = jax.lax.cummax(aff.astype(jnp.float64), axis=0)
-        req_aff_lvl = bp.discretize(aff, aff_max, p["n_levels"])
-        pass1 = req_lvl > avg_lvl
-        dec_ok = dice < bp.p_dec(page_act, max_act)
-
-        # fill candidacy before the (stateful) accept decision
-        if two_level:
-            cand = ~excluded & pass1
-        elif policy in ("no_bypass", "no_bypass_no_ctc", "always_cache"):
-            cand = ~excluded
-        elif policy == "bear":
-            cand = dice < p["bear_fill_prob"]
-        elif policy == "redcache":
-            cand = page_act >= p["redcache_threshold"]
-        elif policy == "mccache":
-            cand = ~is_write
-        else:
-            raise _rvalidate.unknown_policy_error(policy)
-
         # ---- the sequential core: only genuinely stateful arrays ----------
         # The DRAM-cache metadata (tag, affinity level, dirty, valid) packs
         # into one int32 word per slot: one gather + one scatter per step
@@ -482,21 +607,14 @@ def _make_engine(key: _EngineKey):
         def gather(a):
             return jnp.take(jnp.asarray(a), posc, axis=0)
 
-        # one int64 word per request: bits 0 is_write | 1 dec_ok | 2 cand |
-        # 3..7 sector | 8..15 req_aff_lvl | 16 live (pad gate, set after the
-        # shard gather) | 17..39 row group | 40..61 tag — two input streams
-        # (slot + meta) instead of eight keeps the scan's per-step slicing
-        # minimal.
-        meta_tr = (is_write.astype(jnp.int64)
-                   | (dec_ok.astype(jnp.int64) << 1)
-                   | (cand.astype(jnp.int64) << 2)
-                   | (jnp.asarray(xs["sector"], jnp.int64) << 3)
-                   | (req_aff_lvl.astype(jnp.int64) << 8)
-                   | (jnp.asarray(xs["row_group"], jnp.int64) << 17)
-                   | (jnp.asarray(xs["tag"], jnp.int64) << 40))
+        # one int64 word per request (packed on the host): bits 0 is_write |
+        # 1 dec_ok | 2 cand | 3..7 sector | 8..15 req_aff_lvl | 16 live (pad
+        # gate, set after the shard gather) | 17..39 row group | 40..61 tag —
+        # two input streams (slot + meta) instead of eight keeps the scan's
+        # per-step slicing minimal.
         scan_xs = {
             "slot": gather(xs["slot"]),
-            "meta": gather(meta_tr) | (live.astype(jnp.int64) << 16),
+            "meta": gather(xs["meta"]) | (live.astype(jnp.int64) << 16),
         }
 
         def step(carry, x):
@@ -585,121 +703,9 @@ def _make_engine(key: _EngineKey):
         # sentinels land in the dropped overflow slot n
         y_tr = jnp.zeros((key.n + 1,), jnp.int32).at[pos.reshape(-1)].set(
             y_sh.reshape(-1))[: key.n]
-        ys = {
-            "hit": (y_tr & 1) != 0,
-            "c_hit": (y_tr & 2) != 0,
-            "do_fill": (y_tr & 4) != 0,
-            "rejected": (y_tr & 8) != 0,
-            "dec": (y_tr & 16) != 0,
-            "wb": (y_tr & 32) != 0,
-            "need_aff_read": (y_tr & 64) != 0,
-        }
-
-        # ---- vectorized counter reduction ---------------------------------
-        hit = ys["hit"]
-        miss = ~hit
-        c_hit = ys["c_hit"]
-        do_fill = ys["do_fill"]
-        wb = ys["wb"]
-        nar = ys["need_aff_read"]
-
-        # Phased traces reduce every counter per phase (segment-sum over the
-        # trace-order phase_id); the whole-trace totals are then *defined* as
-        # the sum of the per-phase vector, so phase attribution is exact by
-        # construction.  Unphased traces keep the scalar reduction.
-        n_ph = key.phases
-        if n_ph > 1:
-            phase = jnp.asarray(xs["phase"])
-            C = {k: jnp.zeros((n_ph,), jnp.float64) for k in _COUNTERS}
-
-            def add(name, v):
-                C[name] = C[name] + jax.ops.segment_sum(
-                    jnp.asarray(v, jnp.float64), phase, num_segments=n_ph)
-        else:
-            C = {k: jnp.zeros((), jnp.float64) for k in _COUNTERS}
-
-            def add(name, v):
-                C[name] = C[name] + jnp.sum(jnp.asarray(v, jnp.float64))
-
-        probe_cost = p["probe_cost"]
-        if use_ctc:
-            add("ctc_hit", c_hit)
-            add("ctc_miss", ~c_hit)
-            add("probe_cols", jnp.where(c_hit, 0.0, probe_cost))
-            add("dram_busy",
-                jnp.where(c_hit, 0.0, dram.rcd + probe_cost + dram.rp))
-            add("dram_acts", jnp.where(c_hit, 0.0, 1.0))
-        elif not ideal_probe:
-            add("ctc_miss", jnp.ones_like(hit))
-            add("probe_cols", jnp.full(hit.shape, probe_cost))
-            add("dram_busy",
-                jnp.full(hit.shape, dram.rcd + probe_cost + dram.rp))
-            add("dram_acts", jnp.ones_like(hit))
-
-        if two_level:
-            add("bypass_l1", miss & ~excluded & ~pass1)
-            add("bypass_l2", ys["rejected"])
-            add("aff_decs", ys["dec"])
-            if policy == "hms":
-                add("probe_cols", nar)
-                add("dram_busy",
-                    jnp.where(nar, dram.rcd + 1.0 + dram.rp, 0.0))
-                add("dram_acts", nar)
-
-        rd = ~is_write
-        add("hit_r", hit & rd)
-        add("hit_w", hit & is_write)
-        add("miss_r", miss & rd)
-        add("miss_w", miss & is_write)
-        add("demand_dram_rd", hit & rd)
-        add("demand_dram_wr", hit & is_write)
-        dram_share = (dram.rcd + dram.rp) / ncols + jnp.where(
-            is_write, dram.wr / ncols, 0.0)
-        scm_share = (scm.rcd + scm.rp) / ncols + jnp.where(
-            is_write, scm.wr / ncols, 0.0)
-        add("dram_busy", jnp.where(hit, 1.0 + dram_share, 0.0))
-        add("dram_acts", jnp.where(hit, 1.0 / ncols, 0.0))
-        if mc_wt:
-            wt = hit & is_write
-            add("demand_scm_wr", wt)
-            add("scm_busy", jnp.where(wt, 1.0 + scm_share, 0.0))
-            add("scm_acts", jnp.where(wt, 1.0 / ncols, 0.0))
-            add("scm_wr_acts", jnp.where(wt, 1.0 / ncols, 0.0))
-
-        dem_scm_rd = miss & rd & ~do_fill
-        dem_scm_wr = miss & is_write & ~do_fill
-        add("demand_scm_rd", dem_scm_rd)
-        add("demand_scm_wr", dem_scm_wr)
-        add("scm_busy",
-            jnp.where(dem_scm_rd | dem_scm_wr, 1.0 + scm_share, 0.0))
-        add("scm_acts",
-            jnp.where(dem_scm_rd | dem_scm_wr, 1.0 / ncols, 0.0))
-        add("scm_wr_acts", jnp.where(dem_scm_wr, 1.0 / ncols, 0.0))
-
-        cpl = p["cpl"]
-        add("fills", do_fill)
-        add("fill_scm_rd", jnp.where(do_fill, cpl, 0.0))
-        add("fill_dram_wr", jnp.where(do_fill, cpl, 0.0))
-        add("meta_wr_cols", jnp.where(do_fill, p["meta_wr_cost"], 0.0))
-        add("scm_busy", jnp.where(do_fill, scm.rcd + cpl + scm.rp, 0.0))
-        add("dram_busy",
-            jnp.where(do_fill, dram.rcd + cpl + dram.wr + dram.rp
-                      + p["meta_wr_cost"], 0.0))
-        add("scm_acts", do_fill)
-        add("dram_acts", do_fill)
-
-        add("dirty_evicts", wb)
-        add("wb_dram_rd", jnp.where(wb, cpl, 0.0))
-        add("wb_scm_wr", jnp.where(wb, cpl, 0.0))
-        add("dram_busy", jnp.where(wb, dram.rcd + cpl + dram.rp, 0.0))
-        add("scm_busy", jnp.where(wb, scm.rcd + cpl + scm.wr + scm.rp, 0.0))
-        add("dram_acts", wb)
-        add("scm_acts", wb)
-        add("scm_wr_acts", wb)
-
         if split:
-            return (cache_f, ctc_f), C
-        return C
+            return (cache_f, ctc_f), y_tr
+        return y_tr
 
     if split:
         def engine(xs, p, carry, use_replay):
@@ -904,8 +910,8 @@ def _run_split(key: _EngineKey, fn, xs, params, masks):
 
     ``masks`` are the per-config touched masks from :func:`_stitch_masks`,
     with a leading batch axis when ``fn`` is the batched engine.  Returns
-    ``(counters, total_rounds)`` — counters from the converged round only,
-    so they are bit-for-bit the sequential scan's."""
+    ``(decision_words, total_rounds)`` — words from the converged round
+    only, so they are bit-for-bit the sequential scan's."""
     slot_m, set_m = masks
     S, T = key.shards, key.t_segments
     lanes = S * T
@@ -918,9 +924,8 @@ def _run_split(key: _EngineKey, fn, xs, params, masks):
     seg_t = lead + (S, T) + ctc_row.shape
 
     def run(g, use_replay):
-        (cache_f, ctc_f), C = fn(xs, params, g, np.bool_(use_replay))
-        C = {k: np.asarray(v, np.float64) for k, v in C.items()}
-        return (np.asarray(cache_f), np.asarray(ctc_f)), C
+        (cache_f, ctc_f), y = fn(xs, params, g, np.bool_(use_replay))
+        return (np.asarray(cache_f), np.asarray(ctc_f)), np.asarray(y)
 
     def advance(g, out):
         # compose boundary guesses from the segment outputs: a slot's value
@@ -949,15 +954,15 @@ def _run_split(key: _EngineKey, fn, xs, params, masks):
     extra = 0
     if key.replay > 0:
         # warm-up round: replay prefixes live, to produce closer guesses.
-        # Its counters are never accepted — replay perturbs segment state,
+        # Its decisions are never accepted — replay perturbs segment state,
         # so only replay-off rounds carry exact sequential semantics.
         out, _ = run(g, True)
         g = advance(g, out)
         extra = 1
-    C, rounds = tsplit.stitch(
+    y, rounds = tsplit.stitch(
         lambda gg, _r: run(gg, False), g, advance, equal,
         max_rounds=key.t_segments + 1)
-    return C, rounds + extra
+    return y, rounds + extra
 
 
 def _ladder_key(trace: Trace, cfgs: Sequence[HMSConfig], key: _EngineKey,
@@ -1014,10 +1019,11 @@ def _run_hms_scan(trace: Trace, cfg: HMSConfig, pre,
                   entry: str = "simulate") -> Dict[str, np.ndarray]:
     if key is None:
         key = _engine_key(trace, cfg)
+    rs = _request_stream(trace, cfg, pre)
 
     def attempt(k: _EngineKey):
         def thunk():
-            xs = _engine_inputs(trace, cfg, pre, k)
+            xs = _engine_inputs(trace, cfg, pre, rs, k)
             params = _runtime_params(cfg, _local_sets(trace, cfg, k))
             fn = _engine_for(k)
             before = _TRACE_COUNTS.get(k, 0)
@@ -1028,12 +1034,11 @@ def _run_hms_scan(trace: Trace, cfg: HMSConfig, pre,
                     with obs.span("stitch", engine="hms",
                                   segments=k.t_segments, replay=k.replay):
                         masks = _stitch_masks(trace, cfg, k)
-                        C, rounds = _run_split(k, fn, xs, params, masks)
+                        y, rounds = _run_split(k, fn, xs, params, masks)
                 else:
-                    C = fn(xs, params)
-                    # scalar (unphased) or (n_phases,) vector per counter
-                    C = {kk: np.asarray(v, np.float64)
-                         for kk, v in C.items()}
+                    y = np.asarray(fn(xs, params))
+            # scalar (unphased) or (n_phases,) vector per counter
+            C = _reduce_counters(trace, cfg, rs, y)
             return C, rounds, k, _TRACE_COUNTS.get(k, 0) > before
         return thunk
 
@@ -1068,11 +1073,12 @@ def _run_hms_batch(trace: Trace, cfgs: Sequence[HMSConfig], key: _EngineKey,
     float64 per counter."""
     with obs.span("preprocess", trace=trace.name, batch=len(cfgs)):
         pres = [preprocess(trace, c) for c in cfgs]
+        streams = [_request_stream(trace, c, p) for c, p in zip(cfgs, pres)]
 
     def attempt(k: _EngineKey):
         def thunk():
-            xs_list = [_engine_inputs(trace, c, p, k)
-                       for c, p in zip(cfgs, pres)]
+            xs_list = [_engine_inputs(trace, c, p, r, k)
+                       for c, p, r in zip(cfgs, pres, streams)]
             xs = {kk: np.stack([x[kk] for x in xs_list])
                   for kk in xs_list[0]}
             params_list = [_runtime_params(c, _local_sets(trace, c, k))
@@ -1090,11 +1096,12 @@ def _run_hms_batch(trace: Trace, cfgs: Sequence[HMSConfig], key: _EngineKey,
                         pairs = [_stitch_masks(trace, c, k) for c in cfgs]
                         masks = (np.stack([a for a, _ in pairs]),
                                  np.stack([b for _, b in pairs]))
-                        Cs, rounds = _run_split(k, fn, xs, params, masks)
+                        ys, rounds = _run_split(k, fn, xs, params, masks)
                 else:
-                    Cs = fn(xs, params)
-                    Cs = {kk: np.asarray(v, np.float64)
-                          for kk, v in Cs.items()}
+                    ys = np.asarray(fn(xs, params))
+            lanes = [_reduce_counters(trace, c, r, y)
+                     for c, r, y in zip(cfgs, streams, ys)]
+            Cs = {kk: np.stack([C[kk] for C in lanes]) for kk in lanes[0]}
             return Cs, rounds, k, _TRACE_COUNTS.get(k, 0) > before
         return thunk
 
@@ -1342,6 +1349,7 @@ def _finish_hms(trace: Trace, cfg: HMSConfig, C: Dict[str, float],
 # Public entry points.
 # ---------------------------------------------------------------------------
 
+@x64_scoped
 def simulate(trace: Trace, cfg: HMSConfig, nvlink: bool = False) -> SimResult:
     """Simulate ``trace`` on the memory system described by ``cfg``."""
     return _simulate(trace, cfg, nvlink, "simulate")
@@ -1398,6 +1406,7 @@ def _simulate(trace: Trace, cfg: HMSConfig, nvlink: bool,
         return _finish_hms(trace, cfg, C, nvlink)
 
 
+@x64_scoped
 def simulate_many(trace: Trace, configs: Sequence[HMSConfig],
                   nvlink: bool = False) -> List[SimResult]:
     """Simulate one trace under many configs, batching compatible configs.

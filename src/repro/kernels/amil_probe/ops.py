@@ -2,23 +2,19 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from .amil_probe import amil_probe as _kernel
 
 
-def _interp() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def probe(meta, slots, tags, block: int = 256):
-    """meta int32[num_slots]; slots/tags int32[N] (N padded here)."""
+def probe(meta, slots, tags, block: int = 256, *, interpret: bool = False):
+    """meta int32[num_slots]; slots/tags int32[N] (N padded here).
+    ``interpret=True`` runs the kernel in the Pallas interpreter (CPU)."""
     (N,) = slots.shape
     pad = (-N) % block
     if pad:
         slots = jnp.pad(slots, (0, pad))
         tags = jnp.pad(tags, (0, pad), constant_values=-1)
     hit, dirty, aff = _kernel(meta, slots, tags, block=block,
-                              interpret=_interp())
+                              interpret=interpret)
     return hit[:N], dirty[:N], aff[:N]

@@ -96,7 +96,7 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True,
                          block_q: int = 128, block_k: int = 128,
                          kv_real: int | None = None,
                          q_real: int | None = None,
-                         interpret: bool = True):
+                         interpret: bool = False):
     """q: (BH, S, d); k/v: (BH, T, d) — head-flattened, GQA pre-expanded.
 
     ``kv_real``/``q_real``: true lengths when S/T were padded to block

@@ -2,21 +2,17 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from .flash_attention import flash_attention_bhsd
 
 
-def _interp() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def flash_attention(q, k, v, *, causal=True, softcap=0.0,
-                    block_q=128, block_k=128):
+                    block_q=128, block_k=128, interpret=False):
     """q: (B, S, H, hd); k/v: (B, T, KV, hd) (GQA expanded here).
 
     Pads S/T to block multiples, flattens heads, runs the kernel.
+    ``interpret=True`` runs it in the Pallas interpreter (CPU).
     """
     B, S, H, hd = q.shape
     T, KV = k.shape[1], k.shape[2]
@@ -37,6 +33,6 @@ def flash_attention(q, k, v, *, causal=True, softcap=0.0,
     out = flash_attention_bhsd(
         qf, kf, vf, causal=causal, softcap=softcap,
         block_q=block_q, block_k=block_k, kv_real=T, q_real=S,
-        interpret=_interp())
+        interpret=interpret)
     out = out.reshape(B, H, S + pad_s, hd).transpose(0, 2, 1, 3)
     return out[:, :S]

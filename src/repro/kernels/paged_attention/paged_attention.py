@@ -10,6 +10,10 @@ resolved by the DMA engine ahead of compute — the kernel core never touches
 addresses, exactly like the paper's tag-in-last-column fetch resolving a
 whole row of residency in one access.
 
+Pool layout: ``(pool_size, KV, page_size, hd)`` — the kv-head axis sits
+ahead of the page axis, so one (page_size x hd) tile of one kv head is a
+contiguous block whose last two dims meet the TPU's (8, 128) tiling rule.
+
 Grid: (batch, kv_heads, n_pages).  The page dimension iterates sequentially
 on TPU, carrying the online-softmax state in VMEM scratch.  Per-step the
 kernel pulls one (page_size x hd) K tile + V tile per kv head, multiplies
@@ -48,7 +52,7 @@ def _paged_kernel(block_table_ref, lengths_ref,         # scalar prefetch
     @pl.when(page_live)
     def _compute():
         q = q_ref[0, 0]                                  # (G, hd)
-        k = k_ref[0, :, 0, :]                            # (page, hd)
+        k = k_ref[0, 0]                                  # (page, hd)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale  # (G, page)
@@ -64,7 +68,7 @@ def _paged_kernel(block_table_ref, lengths_ref,         # scalar prefetch
         corr = jnp.exp(m_prev - m_new)
         l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
         pv = jax.lax.dot_general(
-            p.astype(v_ref.dtype), v_ref[0, :, 0, :],
+            p.astype(v_ref.dtype), v_ref[0, 0],
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)          # (G, hd)
         acc_ref[...] = acc_ref[...] * corr + pv
@@ -80,15 +84,15 @@ def _paged_kernel(block_table_ref, lengths_ref,         # scalar prefetch
     jax.jit,
     static_argnames=("softcap", "interpret"))
 def paged_attention(q, k_pages, v_pages, block_table, lengths, *,
-                    softcap: float = 0.0, interpret: bool = True):
+                    softcap: float = 0.0, interpret: bool = False):
     """q: (B, KV, G, hd) — one token's query heads grouped by kv head.
-    k_pages/v_pages: (pool_size, page_size, KV, hd) global page pool.
+    k_pages/v_pages: (pool_size, KV, page_size, hd) global page pool.
     block_table: (B, n_pages) int32 pool-slot per logical page.
     lengths: (B,) int32 tokens valid per sequence.
     Returns (B, KV, G, hd).
     """
     B, KV, G, hd = q.shape
-    pool, page_size, KV2, hd2 = k_pages.shape
+    pool, KV2, page_size, hd2 = k_pages.shape
     assert (KV2, hd2) == (KV, hd)
     n_pages = block_table.shape[1]
     scale = float(1.0 / np.sqrt(hd))
@@ -102,10 +106,10 @@ def paged_attention(q, k_pages, v_pages, block_table, lengths, *,
         in_specs=[
             pl.BlockSpec((1, 1, G, hd),
                          lambda b, h, p, bt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda b, h, p, bt, ln: (bt[b, p], 0, h, 0)),
-            pl.BlockSpec((1, page_size, 1, hd),
-                         lambda b, h, p, bt, ln: (bt[b, p], 0, h, 0)),
+            pl.BlockSpec((1, 1, page_size, hd),
+                         lambda b, h, p, bt, ln: (bt[b, p], h, 0, 0)),
+            pl.BlockSpec((1, 1, page_size, hd),
+                         lambda b, h, p, bt, ln: (bt[b, p], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, hd),
                                lambda b, h, p, bt, ln: (b, h, 0, 0)),

@@ -14,14 +14,13 @@ def paged_attention_reference(q, k_pages, v_pages, block_table, lengths, *,
     Shapes as in ``paged_attention``.
     """
     B, KV, G, hd = q.shape
-    pool, page_size, _, _ = k_pages.shape
+    pool, _, page_size, _ = k_pages.shape
     n_pages = block_table.shape[1]
     T = n_pages * page_size
 
-    k = k_pages[block_table]                 # (B, n_pages, page, KV, hd)
-    v = v_pages[block_table]
-    k = k.reshape(B, T, KV, hd)
-    v = v.reshape(B, T, KV, hd)
+    # (B, n_pages, KV, page, hd) -> (B, T, KV, hd)
+    k = jnp.swapaxes(k_pages[block_table], 2, 3).reshape(B, T, KV, hd)
+    v = jnp.swapaxes(v_pages[block_table], 2, 3).reshape(B, T, KV, hd)
 
     logits = jnp.einsum("bkgh,btkh->bkgt", q.astype(jnp.float32),
                         k.astype(jnp.float32)) * float(1.0 / np.sqrt(hd))

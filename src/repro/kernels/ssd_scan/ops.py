@@ -2,19 +2,15 @@
 
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from .ssd_scan import ssd_scan as _kernel
 
 
-def _interp() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def ssd(x, dt, A, B, C, chunk: int):
+def ssd(x, dt, A, B, C, chunk: int, *, interpret: bool = False):
     """Model layout (matches ssd_reference): x (b,l,h,p), dt (b,l,h),
-    A (h,), B/C (b,l,g,n).  Returns y (b,l,h,p) (no final state)."""
+    A (h,), B/C (b,l,g,n).  Returns y (b,l,h,p) (no final state).
+    ``interpret=True`` runs the kernel in the Pallas interpreter (CPU)."""
     b, l, h, p = x.shape
     g, n = B.shape[2], B.shape[3]
     pad = (-l) % chunk
@@ -32,6 +28,6 @@ def ssd(x, dt, A, B, C, chunk: int):
     tr = lambda t: jnp.moveaxis(t, 2, 1)
     y = _kernel(tr(xdt), tr(dta)[..., None], tr(Bh.astype(jnp.float32)),
                 tr(Ch.astype(jnp.float32)), chunk=chunk,
-                interpret=_interp())
+                interpret=interpret)
     y = jnp.moveaxis(y, 1, 2)[:, :l]
     return y.astype(x.dtype)

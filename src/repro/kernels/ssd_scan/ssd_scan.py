@@ -12,7 +12,9 @@ MXU matmuls on (chunk x state)/(chunk x head_dim) tiles:
     y_off  = (C ⊙ decay) S_prev  inter-chunk contribution
 
 and one rank-k update of the carried state.  All decay math (segsum) is
-computed in-register from the chunk's dtA vector.
+computed in-register from the chunk's dtA vector; its prefix sums are
+lower-triangular mask matmuls (Mosaic has no cumsum), one per layout the
+decay matrix needs (a column and a row).
 """
 
 from __future__ import annotations
@@ -37,12 +39,19 @@ def _ssd_kernel(x_ref, dta_ref, b_ref, c_ref, y_ref, s_ref, *, chunk: int):
     Bm = b_ref[0, 0].astype(jnp.float32)           # (cs, n)
     Cm = c_ref[0, 0].astype(jnp.float32)           # (cs, n)
 
-    cum = jnp.cumsum(dta[:, 0])                    # (cs,)
-    # L[i, j] = exp(cum_i - cum_j) for i >= j else 0
-    diff = cum[:, None] - cum[None, :]
+    # tri[i, j] = i >= j: cum = tri @ dta is the inclusive prefix sum
     tri = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
         jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    L = jnp.where(tri, jnp.exp(diff), 0.0)
+    trif = tri.astype(jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    cum = jax.lax.dot_general(
+        trif, dta, (((1,), (0,)), ((), ())), precision=hi,
+        preferred_element_type=jnp.float32)        # (cs, 1)
+    cum_row = jax.lax.dot_general(
+        dta, trif, (((0,), (1,)), ((), ())), precision=hi,
+        preferred_element_type=jnp.float32)        # (1, cs)
+    # L[i, j] = exp(cum_i - cum_j) for i >= j else 0
+    L = jnp.where(tri, jnp.exp(cum - cum_row), 0.0)
 
     scores = jax.lax.dot_general(
         Cm, Bm, (((1,), (1,)), ((), ())),
@@ -53,14 +62,15 @@ def _ssd_kernel(x_ref, dta_ref, b_ref, c_ref, y_ref, s_ref, *, chunk: int):
 
     s_prev = s_ref[...]                            # (hp, n)
     y_off = jax.lax.dot_general(
-        Cm * jnp.exp(cum)[:, None], s_prev,
+        Cm * jnp.exp(cum), s_prev,
         (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)        # (cs, hp)
 
-    total = cum[-1]
-    decay_to_end = jnp.exp(total - cum)            # (cs,)
+    last = jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) == chunk - 1
+    total = jnp.sum(jnp.where(last, cum, 0.0))     # cum[-1], as a scalar
+    decay_to_end = jnp.exp(total - cum)            # (cs, 1)
     s_new = jnp.exp(total) * s_prev + jax.lax.dot_general(
-        x * decay_to_end[:, None], Bm, (((0,), (0,)), ((), ())),
+        x * decay_to_end, Bm, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)        # (hp, n)
     s_ref[...] = s_new
 
@@ -68,7 +78,7 @@ def _ssd_kernel(x_ref, dta_ref, b_ref, c_ref, y_ref, s_ref, *, chunk: int):
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def ssd_scan(x, dta, Bh, Ch, *, chunk: int, interpret: bool = True):
+def ssd_scan(x, dta, Bh, Ch, *, chunk: int, interpret: bool = False):
     """x: (B, H, L, hp); dta: (B, H, L, 1); Bh/Ch: (B, H, L, n).
 
     ``dta`` = dt * A (already multiplied, post-softplus dt); B/C already

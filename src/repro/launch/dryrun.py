@@ -1,6 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-
 """Multi-pod dry-run driver.
 
 For one (arch x shape x mesh) cell:
@@ -27,6 +24,7 @@ Usage:
 import argparse
 import dataclasses
 import json
+import os
 import re
 import sys
 import time
@@ -309,6 +307,9 @@ def lower_cell(arch: str, shape_name: str, multi_pod: bool,
 
 
 def main(argv=None):
+    # 512 virtual host devices back the production meshes; XLA reads the
+    # flag when the first backend starts, so this precedes any JAX use
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")

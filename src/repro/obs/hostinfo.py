@@ -38,13 +38,15 @@ def git_info() -> Dict[str, Optional[object]]:
 
 @functools.lru_cache(maxsize=1)
 def host_metadata() -> Dict[str, object]:
-    """Process-stable host descriptor: platform, Python/JAX versions, and
-    the git identity.  Benchmark artifacts extend this with engine tuning
+    """Process-stable host descriptor: platform, Python/JAX versions, the
+    default device as JAX reports it (platform, kind, count), and the git
+    identity.  Benchmark artifacts extend this with engine tuning
     constants (``benchmarks.common.host_metadata``)."""
     import platform
 
     import jax
 
+    devices = jax.devices()
     return {
         "cpu_count": os.cpu_count(),
         "platform": platform.platform(),
@@ -52,5 +54,8 @@ def host_metadata() -> Dict[str, object]:
         "python": platform.python_version(),
         "jax": jax.__version__,
         "jax_backend": jax.default_backend(),
+        "device_platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
         **git_info(),
     }

@@ -59,6 +59,7 @@ class Engine:
             max_seqs=scfg.max_batch)
         self.queue: List[Request] = []
         self.done: Dict[int, Request] = {}
+        self.nonfinite_logits = 0    # NaN/inf logits seen over every step
 
         self._decode = jax.jit(
             lambda p, t, c, pos: decode_step(p, t, c, pos, cfg, ctx))
@@ -97,6 +98,7 @@ class Engine:
                     for _ in range(min(self.scfg.max_batch,
                                        len(self.queue)))]
             logits, cache, S = self._prefill_batch(reqs)
+            self._count_nonfinite(logits)
             tok = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
             outs = [[int(t)] for t in np.asarray(tok[:, 0])]
             pos = S + (self.cfg.n_patches
@@ -110,6 +112,7 @@ class Engine:
                     list(range(len(reqs))))
                 lg, cache = self._decode(self.params, tok, cache,
                                          jnp.int32(pos))
+                self._count_nonfinite(lg)
                 tok = jnp.argmax(lg, axis=-1)[:, None].astype(jnp.int32)
                 for i in range(len(reqs)):
                     if stepi < reqs[i].max_new - 1:
@@ -120,6 +123,9 @@ class Engine:
                 r.out = np.asarray(outs[i][:r.max_new], np.int32)
                 self.done[r.rid] = r
         return {rid: r.out for rid, r in self.done.items()}
+
+    def _count_nonfinite(self, logits) -> None:
+        self.nonfinite_logits += int(jnp.sum(~jnp.isfinite(logits)))
 
     @property
     def kv_stats(self) -> Dict[str, int]:

@@ -15,16 +15,15 @@ Do not "optimize" this module; its value is being a frozen reference.
 from __future__ import annotations
 
 import jax
-
-jax.config.update("jax_enable_x64", True)
-
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.timing import COLUMN_BYTES, UM_PAGE_BYTES, HMSConfig
 from repro.core.traces import Trace
+from repro.core.x64 import x64_scoped
 
 
+@x64_scoped
 def run_um_reference(trace: Trace, cfg: HMSConfig, nvlink: bool = False):
     """Page-granular UM simulation: FIFO frames + TBN-style chunk migration.
 

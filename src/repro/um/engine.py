@@ -67,9 +67,6 @@ import weakref
 from typing import Dict, List, Sequence
 
 import jax
-
-jax.config.update("jax_enable_x64", True)
-
 import jax.numpy as jnp
 import numpy as np
 
@@ -77,6 +74,7 @@ from repro import obs
 from repro.core import costmodel, tsplit
 from repro.core.timing import COLUMN_BYTES, UM_PAGE_BYTES, HMSConfig
 from repro.core.traces import Trace
+from repro.core.x64 import x64_scoped
 from repro.resilience import guard as _guard
 from repro.resilience import sweepckpt as _sweepckpt
 from repro.resilience import validate as _rvalidate
@@ -322,12 +320,14 @@ def _make_um_engine(key: _UMKey):
         # Per-phase reduction (trace-order segment sums); totals are the
         # sums of these vectors, so phase attribution is exact.  Split
         # lanes flatten (T, L) row-major — core steps stay in trace order
-        # and gated replay/pad steps contribute exact zeros.
+        # and gated replay/pad steps contribute exact zeros.  Event counts
+        # stay int32 on the device (exact on every backend) and become
+        # float64 on the host.
         seg_ids = phase.reshape(-1) if split else phase
 
         def red(v):
             return jax.ops.segment_sum(
-                jnp.asarray(v, jnp.float64).reshape(-1), seg_ids,
+                jnp.asarray(v, jnp.int32).reshape(-1), seg_ids,
                 num_segments=P)
 
         C = {
@@ -542,6 +542,7 @@ def _um_reference_attempt(trace: Trace, run_specs: Sequence[UMSpec],
     return Cs, 1, dataclasses.replace(key, t_segments=1, replay=0), False
 
 
+@x64_scoped
 def simulate_um_many(trace: Trace, specs: Sequence[UMSpec]) -> List[UMResult]:
     """Run a batch of UM configs over one trace: one compiled, vmapped scan
     for every spec not already memoized, with duplicate specs deduped to a

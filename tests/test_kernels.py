@@ -1,5 +1,7 @@
 """Per-kernel interpret-mode validation against the pure-jnp oracles:
-shape/dtype sweeps + assert_allclose, plus hypothesis property tests."""
+shape/dtype sweeps + assert_allclose, plus hypothesis property tests.
+Every kernel call passes ``interpret=True`` (the Pallas interpreter on the
+CPU); ``test_tpu_compile.py`` compiles the same kernels for the chip."""
 
 import jax
 import jax.numpy as jnp
@@ -44,7 +46,8 @@ def test_flash_matches_reference(S, T, H, KV, hd, dtype, causal):
     q = jnp.asarray(RNG.standard_normal((B, S, H, hd)), dtype)
     k = jnp.asarray(RNG.standard_normal((B, T, KV, hd)), dtype)
     v = jnp.asarray(RNG.standard_normal((B, T, KV, hd)), dtype)
-    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
+    out = flash_attention(q, k, v, causal=causal, block_q=64, block_k=64,
+                          interpret=True)
     G = H // KV
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
     kf = jnp.repeat(k, G, 2).transpose(0, 2, 1, 3).reshape(B * H, T, hd)
@@ -61,7 +64,8 @@ def test_flash_softcap():
     q = jnp.asarray(RNG.standard_normal((B, S, H, hd)), jnp.float32)
     k = jnp.asarray(RNG.standard_normal((B, S, H, hd)), jnp.float32)
     v = jnp.asarray(RNG.standard_normal((B, S, H, hd)), jnp.float32)
-    out = flash_attention(q, k, v, causal=True, softcap=30.0)
+    out = flash_attention(q, k, v, causal=True, softcap=30.0,
+                          interpret=True)
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
     kf = k.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
     vf = v.transpose(0, 2, 1, 3).reshape(B * H, S, hd)
@@ -84,11 +88,11 @@ def test_flash_softcap():
 def test_paged_matches_reference(B, H, KV, hd, ps, npg, dtype):
     pool = npg * B + 7
     q = jnp.asarray(RNG.standard_normal((B, 1, H, hd)), dtype)
-    kp = jnp.asarray(RNG.standard_normal((pool, ps, KV, hd)), dtype)
-    vp = jnp.asarray(RNG.standard_normal((pool, ps, KV, hd)), dtype)
+    kp = jnp.asarray(RNG.standard_normal((pool, KV, ps, hd)), dtype)
+    vp = jnp.asarray(RNG.standard_normal((pool, KV, ps, hd)), dtype)
     bt = jnp.asarray(RNG.integers(0, pool, (B, npg)), jnp.int32)
     lengths = jnp.asarray(RNG.integers(1, npg * ps + 1, (B,)), jnp.int32)
-    out = paged_decode_attention(q, kp, vp, bt, lengths)
+    out = paged_decode_attention(q, kp, vp, bt, lengths, interpret=True)
     ref = paged_attention_reference(
         q[:, 0].reshape(B, KV, H // KV, hd), kp, vp, bt, lengths
     ).reshape(B, 1, H, hd)
@@ -101,13 +105,13 @@ def test_paged_ignores_out_of_length_pages():
     """Pages past `length` must not affect the output (residency masking)."""
     B, H, KV, hd, ps, npg, pool = 1, 2, 2, 64, 16, 4, 16
     q = jnp.asarray(RNG.standard_normal((B, 1, H, hd)), jnp.float32)
-    kp = jnp.asarray(RNG.standard_normal((pool, ps, KV, hd)), jnp.float32)
-    vp = jnp.asarray(RNG.standard_normal((pool, ps, KV, hd)), jnp.float32)
+    kp = jnp.asarray(RNG.standard_normal((pool, KV, ps, hd)), jnp.float32)
+    vp = jnp.asarray(RNG.standard_normal((pool, KV, ps, hd)), jnp.float32)
     bt1 = jnp.asarray([[0, 1, 2, 3]], jnp.int32)
     bt2 = jnp.asarray([[0, 1, 9, 9]], jnp.int32)   # garbage beyond length
     lengths = jnp.asarray([2 * ps], jnp.int32)
-    o1 = paged_decode_attention(q, kp, vp, bt1, lengths)
-    o2 = paged_decode_attention(q, kp, vp, bt2, lengths)
+    o1 = paged_decode_attention(q, kp, vp, bt1, lengths, interpret=True)
+    o2 = paged_decode_attention(q, kp, vp, bt2, lengths, interpret=True)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2), atol=1e-6)
 
 
@@ -127,7 +131,7 @@ def test_ssd_kernel_matches_reference(l, h, p, g, n, chunk):
     A = -jnp.asarray(RNG.random((h,)) * 0.5 + 0.5, jnp.float32)
     B = jnp.asarray(RNG.standard_normal((b, l, g, n)) * 0.3, jnp.float32)
     C = jnp.asarray(RNG.standard_normal((b, l, g, n)) * 0.3, jnp.float32)
-    yk = ssd(x, dt, A, B, C, chunk=chunk)
+    yk = ssd(x, dt, A, B, C, chunk=chunk, interpret=True)
     yr, _ = ssd_reference(x, dt, A, B, C, chunk)
     np.testing.assert_allclose(np.asarray(yk), np.asarray(yr), atol=3e-4,
                                rtol=3e-4)
@@ -181,7 +185,7 @@ def test_amil_probe_property(n_req, n_slots_16, seed):
     meta = jnp.asarray(rng.integers(0, 64, (n_slots,)), jnp.int32)
     slots = jnp.asarray(rng.integers(0, n_slots, (n_req,)), jnp.int32)
     tags = jnp.asarray(rng.integers(0, 4, (n_req,)), jnp.int32)
-    h1, d1, a1 = probe(meta, slots, tags)
+    h1, d1, a1 = probe(meta, slots, tags, interpret=True)
     h2, d2, a2 = amil_probe_reference(meta, slots, tags)
     assert (np.asarray(h1) == np.asarray(h2)).all()
     assert (np.asarray(d1) == np.asarray(d2)).all()
@@ -201,6 +205,7 @@ def test_amil_pack_roundtrip():
     assert (np.asarray(a) == np.asarray(aff)).all()
 
 
+@jax.enable_x64(True)                 # the row word is a uint64
 def test_amil_row_word_roundtrip():
     from repro.core.amil import (pack_row_meta, row_meta_to_u64,
                                  u64_to_row_meta)

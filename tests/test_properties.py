@@ -136,17 +136,21 @@ def test_ctc_disabled_ways_never_allocated(sets, enabled, ops):
 def test_ctc_packed_variant_matches_reference_layout(sets, enabled, ops):
     """The simulator's packed int64 CTC (one gather/scatter/argmax per
     access) must track the reference probe_fill_touch state bit-for-bit."""
+    import jax
+
     from repro.core import ctc
 
     ways = 8
-    state = ctc.init_state(sets, ways, 8)
-    pstate = ctc.packed_init(sets, ways, 8)
-    for rg, sector in ops:
-        state, hit = ctc.probe_fill_touch(state, jnp.int32(rg),
-                                          jnp.int32(sector), enabled, sets)
-        pstate, phit = ctc.probe_fill_touch_packed(
-            pstate, jnp.int32(rg), jnp.int32(sector), enabled, sets)
-        assert bool(hit) == bool(phit)
+    with jax.enable_x64(True):            # the packed ways are int64
+        state = ctc.init_state(sets, ways, 8)
+        pstate = ctc.packed_init(sets, ways, 8)
+        for rg, sector in ops:
+            state, hit = ctc.probe_fill_touch(state, jnp.int32(rg),
+                                              jnp.int32(sector), enabled,
+                                              sets)
+            pstate, phit = ctc.probe_fill_touch_packed(
+                pstate, jnp.int32(rg), jnp.int32(sector), enabled, sets)
+            assert bool(hit) == bool(phit)
     dec = _unpack_packed(pstate)
     np.testing.assert_array_equal(np.asarray(state["tags"]), dec["tags"])
     np.testing.assert_array_equal(np.asarray(state["age"]), dec["age"])

@@ -391,6 +391,20 @@ def test_um_ladder_reaches_reference(monkeypatch):
     assert rec.counter_digest == brec.counter_digest
 
 
+def test_um_bisect_leaves_the_process_32_bit():
+    """A bisecting UM batch re-enters its own entry point; the engines'
+    64-bit scope must still close when the outer call returns."""
+    import jax
+    t = _rand_trace(5)
+    specs = [um.UMSpec(n_frames=48, chunk=4),
+             um.UMSpec(n_frames=48, chunk=4, nvlink=True)]
+    base, brec = _um_digest_run(t, specs)
+    got, rec = _um_digest_run(t, specs, "oom@1,oom@2")   # retry, bisect
+    assert rec.ladder_rung == "bisect"
+    assert rec.counter_digest == brec.counter_digest
+    assert not jax.config.jax_enable_x64
+
+
 def test_hms_batch_bisects_on_oom_bit_exact():
     t = _rand_trace(6)
     cfgs = [HMSConfig(footprint=t.footprint, ctc_ways=w)
