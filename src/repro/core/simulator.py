@@ -794,7 +794,7 @@ def _obs_hms_record(entry: str, trace: Trace, key: _EngineKey, width: int,
                     rounds: int = 1, outcome=None,
                     cfgs: Sequence[HMSConfig] = (),
                     lanes: Sequence[Dict[str, np.ndarray]] = (),
-                    plan=None) -> None:
+                    plan=None, input_bytes: int | None = None) -> None:
     """Build + emit one HMS ledger record (caller gates on obs.enabled()).
     ``key`` is the engine key that actually produced the counters (the
     degradation ladder may have descended from the planned one);
@@ -806,7 +806,8 @@ def _obs_hms_record(entry: str, trace: Trace, key: _EngineKey, width: int,
     the UM-overflow term that makes ``nvlink`` matter.  ``plan`` is the
     :class:`~repro.core.costmodel.SplitPlan` behind the *planned* shape
     (schema 4: prediction + rejected alternatives ride the record even
-    when the ladder descended)."""
+    when the ladder descended).  ``input_bytes`` is what the engine call
+    staged to the device (schema 5)."""
     obs.record(obs.RunRecord(
         entry=entry, engine="hms", trace=trace.name, n=trace.n,
         phases=key.phases, engine_key=_fingerprint(key, width),
@@ -826,6 +827,7 @@ def _obs_hms_record(entry: str, trace: Trace, key: _EngineKey, width: int,
         plan_alternatives=list(plan.alternatives) or None
         if plan is not None else None,
         calib_fingerprint=costmodel.active_profile().fingerprint,
+        input_bytes=input_bytes,
         host=obs.host_metadata(), **obs.git_info()))
 
 
@@ -1011,7 +1013,7 @@ def _hms_reference_attempt(trace: Trace, cfgs: Sequence[HMSConfig],
     else:
         C = {k: np.asarray([d[k] for d in per], np.float64)
              for k in per[0]}
-    return C, 1, label, False
+    return C, 1, label, False, None
 
 
 def _run_hms_scan(trace: Trace, cfg: HMSConfig, pre,
@@ -1019,12 +1021,14 @@ def _run_hms_scan(trace: Trace, cfg: HMSConfig, pre,
                   entry: str = "simulate") -> Dict[str, np.ndarray]:
     if key is None:
         key = _engine_key(trace, cfg)
-    rs = _request_stream(trace, cfg, pre)
+    with obs.span("request_stream", engine="hms", configs=1):
+        rs = _request_stream(trace, cfg, pre)
 
     def attempt(k: _EngineKey):
         def thunk():
-            xs = _engine_inputs(trace, cfg, pre, rs, k)
-            params = _runtime_params(cfg, _local_sets(trace, cfg, k))
+            with obs.span("engine_inputs", engine="hms", batch=1):
+                xs = _engine_inputs(trace, cfg, pre, rs, k)
+                params = _runtime_params(cfg, _local_sets(trace, cfg, k))
             fn = _engine_for(k)
             before = _TRACE_COUNTS.get(k, 0)
             rounds = 1
@@ -1036,10 +1040,12 @@ def _run_hms_scan(trace: Trace, cfg: HMSConfig, pre,
                         masks = _stitch_masks(trace, cfg, k)
                         y, rounds = _run_split(k, fn, xs, params, masks)
                 else:
-                    y = np.asarray(fn(xs, params))
+                    y = obs.engine_call("hms", fn, (xs, params), np.asarray)
             # scalar (unphased) or (n_phases,) vector per counter
-            C = _reduce_counters(trace, cfg, rs, y)
-            return C, rounds, k, _TRACE_COUNTS.get(k, 0) > before
+            with obs.span("reduce_counters", engine="hms", batch=1):
+                C = _reduce_counters(trace, cfg, rs, y)
+            return (C, rounds, k, _TRACE_COUNTS.get(k, 0) > before,
+                    obs.staged_bytes(xs, params))
         return thunk
 
     rungs = [(f"S{k.shards}T{k.t_segments}", attempt(k))
@@ -1049,7 +1055,8 @@ def _run_hms_scan(trace: Trace, cfg: HMSConfig, pre,
             ("reference",
              lambda: _hms_reference_attempt(trace, [cfg], key)))
     t0 = time.perf_counter()
-    (C, rounds, used, compiled), outcome = _guard.run_ladder("hms", rungs)
+    (C, rounds, used, compiled, staged), outcome = _guard.run_ladder(
+        "hms", rungs)
     wall = time.perf_counter() - t0
     plan = _PLAN_BY_KEY.get(key)
     if outcome.rung != "reference":
@@ -1058,9 +1065,11 @@ def _run_hms_scan(trace: Trace, cfg: HMSConfig, pre,
             costmodel.check_plan_drift(_fingerprint(used, 1),
                                        plan.predicted_us, wall, compiled)
     if obs.enabled():
-        _obs_hms_record(entry, trace, used, 1, compiled, wall,
-                        obs.counter_digest(C), rounds, outcome,
-                        cfgs=[cfg], lanes=[C], plan=plan)
+        with obs.span("obs_record", engine="hms"):
+            _obs_hms_record(entry, trace, used, 1, compiled, wall,
+                            obs.counter_digest(C), rounds, outcome,
+                            cfgs=[cfg], lanes=[C], plan=plan,
+                            input_bytes=staged)
     return C
 
 
@@ -1073,18 +1082,21 @@ def _run_hms_batch(trace: Trace, cfgs: Sequence[HMSConfig], key: _EngineKey,
     float64 per counter."""
     with obs.span("preprocess", trace=trace.name, batch=len(cfgs)):
         pres = [preprocess(trace, c) for c in cfgs]
-        streams = [_request_stream(trace, c, p) for c, p in zip(cfgs, pres)]
+        with obs.span("request_stream", engine="hms", configs=len(cfgs)):
+            streams = [_request_stream(trace, c, p)
+                       for c, p in zip(cfgs, pres)]
 
     def attempt(k: _EngineKey):
         def thunk():
-            xs_list = [_engine_inputs(trace, c, p, r, k)
-                       for c, p, r in zip(cfgs, pres, streams)]
-            xs = {kk: np.stack([x[kk] for x in xs_list])
-                  for kk in xs_list[0]}
-            params_list = [_runtime_params(c, _local_sets(trace, c, k))
-                           for c in cfgs]
-            params = {kk: np.stack([p[kk] for p in params_list])
-                      for kk in params_list[0]}
+            with obs.span("engine_inputs", engine="hms", batch=len(cfgs)):
+                xs_list = [_engine_inputs(trace, c, p, r, k)
+                           for c, p, r in zip(cfgs, pres, streams)]
+                xs = {kk: np.stack([x[kk] for x in xs_list])
+                      for kk in xs_list[0]}
+                params_list = [_runtime_params(c, _local_sets(trace, c, k))
+                               for c in cfgs]
+                params = {kk: np.stack([p[kk] for p in params_list])
+                          for kk in params_list[0]}
             fn = _batched_engine_for(k)
             before = _TRACE_COUNTS.get(k, 0)
             rounds = 1
@@ -1098,11 +1110,14 @@ def _run_hms_batch(trace: Trace, cfgs: Sequence[HMSConfig], key: _EngineKey,
                                  np.stack([b for _, b in pairs]))
                         ys, rounds = _run_split(k, fn, xs, params, masks)
                 else:
-                    ys = np.asarray(fn(xs, params))
-            lanes = [_reduce_counters(trace, c, r, y)
-                     for c, r, y in zip(cfgs, streams, ys)]
-            Cs = {kk: np.stack([C[kk] for C in lanes]) for kk in lanes[0]}
-            return Cs, rounds, k, _TRACE_COUNTS.get(k, 0) > before
+                    ys = obs.engine_call("hms", fn, (xs, params), np.asarray)
+            with obs.span("reduce_counters", engine="hms", batch=len(cfgs)):
+                lanes = [_reduce_counters(trace, c, r, y)
+                         for c, r, y in zip(cfgs, streams, ys)]
+                Cs = {kk: np.stack([C[kk] for C in lanes])
+                      for kk in lanes[0]}
+            return (Cs, rounds, k, _TRACE_COUNTS.get(k, 0) > before,
+                    obs.staged_bytes(xs, params))
         return thunk
 
     def bisect():
@@ -1113,7 +1128,7 @@ def _run_hms_batch(trace: Trace, cfgs: Sequence[HMSConfig], key: _EngineKey,
         A = _run_hms_batch(trace, cfgs[:h], key, entry)
         B = _run_hms_batch(trace, cfgs[h:], key, entry)
         Cs = {kk: np.concatenate([A[kk], B[kk]], axis=0) for kk in A}
-        return Cs, 1, key, False
+        return Cs, 1, key, False, None
 
     rungs = [(f"S{k.shards}T{k.t_segments}", attempt(k))
              for k in _hms_ladder_keys(trace, cfgs, key)]
@@ -1122,7 +1137,7 @@ def _run_hms_batch(trace: Trace, cfgs: Sequence[HMSConfig], key: _EngineKey,
             ("reference",
              lambda: _hms_reference_attempt(trace, cfgs, key)))
     t0 = time.perf_counter()
-    (Cs, rounds, used, compiled), outcome = _guard.run_ladder(
+    (Cs, rounds, used, compiled, staged), outcome = _guard.run_ladder(
         "hms_batch", rungs, bisect=bisect if len(cfgs) > 1 else None)
     wall = time.perf_counter() - t0
     plan = _PLAN_BY_KEY.get(key)
@@ -1132,12 +1147,13 @@ def _run_hms_batch(trace: Trace, cfgs: Sequence[HMSConfig], key: _EngineKey,
             costmodel.check_plan_drift(_fingerprint(used, len(cfgs)),
                                        plan.predicted_us, wall, compiled)
     if obs.enabled():
-        lanes = [{k: v[j] for k, v in Cs.items()}
-                 for j in range(len(cfgs))]
-        _obs_hms_record(
-            entry, trace, used, len(cfgs), compiled, wall,
-            obs.counter_digest(lanes), rounds, outcome,
-            cfgs=cfgs, lanes=lanes, plan=plan)
+        with obs.span("obs_record", engine="hms"):
+            lanes = [{k: v[j] for k, v in Cs.items()}
+                     for j in range(len(cfgs))]
+            _obs_hms_record(
+                entry, trace, used, len(cfgs), compiled, wall,
+                obs.counter_digest(lanes), rounds, outcome,
+                cfgs=cfgs, lanes=lanes, plan=plan, input_bytes=staged)
     return Cs
 
 

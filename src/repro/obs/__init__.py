@@ -10,7 +10,8 @@ One facade over everything observable about the simulation engines:
   the ``REPRO_OBS_DIR`` env var streams records to JSONL.
 * **Span tracer** (:mod:`.spans`) — ``span("preprocess")`` etc. through
   the engines and benchmark suites, exportable to Chrome/Perfetto
-  trace-event JSON via :func:`export_trace`.
+  trace-event JSON via :func:`export_trace`, and mirrored as
+  ``jax.profiler`` annotations while a profiler trace runs.
 * **Retrace sentinel** (:mod:`.sentinel`) — ``cache_stats()`` /
   ``reset()`` / ``assert_no_retrace()`` promote the engines' scattered
   jit-cache counters into one contract: a warm engine must never silently
@@ -39,6 +40,7 @@ from .ledger import (
     obs_dir,
     record,
     records,
+    staged_bytes,
 )
 from .sentinel import (
     RetraceError,
@@ -48,7 +50,7 @@ from .sentinel import (
     engine_runs,
     reset,
 )
-from .spans import clear_events, events, export_trace, span
+from .spans import clear_events, engine_call, events, export_trace, span
 from .spans import set_enabled as _spans_set_enabled
 
 
@@ -97,9 +99,9 @@ __all__ = [
     # ledger
     "RunRecord", "enable", "disable", "enabled", "record", "records",
     "clear_records", "load_ledger", "ledger_path", "obs_dir",
-    "counter_digest", "compile_split",
+    "counter_digest", "compile_split", "staged_bytes",
     # spans
-    "span", "events", "clear_events", "export_trace",
+    "span", "engine_call", "events", "clear_events", "export_trace",
     # sentinel
     "cache_stats", "reset", "assert_no_retrace", "RetraceError",
     "engine_run", "engine_runs",

@@ -59,6 +59,13 @@ def counter_digest(counters) -> str:
     return h.hexdigest()[:16]
 
 
+def staged_bytes(*arrays: Mapping[str, object]) -> int:
+    """Bytes of the host arrays one engine call stages to the device: the
+    ``nbytes`` summed over the values of the given dicts (inputs, runtime
+    parameters)."""
+    return sum(int(np.asarray(v).nbytes) for d in arrays for v in d.values())
+
+
 @dataclasses.dataclass
 class RunRecord:
     """One engine invocation, as the ledger sees it.
@@ -117,6 +124,10 @@ class RunRecord:
     plan_predicted_us: Optional[float] = None
     plan_alternatives: Optional[List[Dict[str, object]]] = None
     calib_fingerprint: Optional[str] = None
+    # host arrays one engine call staged to the device (xs plus runtime
+    # parameters, summed ``nbytes``); None where no engine ran (reference
+    # rung, bisected batch, memoized UM call) and on pre-schema-5 records
+    input_bytes: Optional[int] = None
     # run identity
     git_sha: Optional[str] = None
     git_dirty: Optional[bool] = None

@@ -8,6 +8,12 @@ span records wall-clock begin/duration (``perf_counter_ns``) plus the
 thread id; nesting falls out of the complete-event ("ph": "X") encoding —
 Perfetto reconstructs the stack from containment per thread.
 
+An enabled span also opens a ``jax.profiler.TraceAnnotation`` of the same
+name for its extent, so while a ``jax.profiler`` trace runs the spans sit
+on its host timeline, on the profiler's clock, beside the device's
+operations.  The span's arguments are formatted into the annotation only
+while a profiler session records it.
+
 Export with :func:`export_trace`; load the JSON at https://ui.perfetto.dev
 or chrome://tracing.
 """
@@ -40,19 +46,36 @@ class _NullSpan:
 _NULL = _NullSpan()
 
 
+_ANNOTATION = None               # jax.profiler.TraceAnnotation, on first use
+
+
+def _annotation(name: str, args: Dict[str, object]):
+    global _ANNOTATION
+    if _ANNOTATION is None:
+        # lazy: importing this package must not import jax
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    if args and _ANNOTATION.is_enabled():
+        return _ANNOTATION(name, **args)
+    return _ANNOTATION(name)
+
+
 class _Span:
-    __slots__ = ("name", "args", "t0")
+    __slots__ = ("name", "args", "t0", "ann")
 
     def __init__(self, name: str, args: Dict[str, object]):
         self.name = name
         self.args = args
 
     def __enter__(self):
+        self.ann = _annotation(self.name, self.args)
+        self.ann.__enter__()
         self.t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter_ns() - self.t0
+        self.ann.__exit__(*exc)
         with _LOCK:
             _EVENTS.append((self.name, self.t0, dur,
                             threading.get_ident(), self.args))
@@ -65,6 +88,21 @@ def span(name: str, **args):
     if not _ENABLED:
         return _NULL
     return _Span(name, args)
+
+
+def engine_call(engine: str, fn, args, readback):
+    """One call of a jitted engine as three spans: ``engine_dispatch``
+    (the call until it returns), ``engine_wait`` (until its outputs are
+    ready on the device) and ``engine_readback`` (``readback`` of the
+    outputs, their copy to the host).  Returns what ``readback`` does."""
+    import jax
+
+    with span("engine_dispatch", engine=engine):
+        out = fn(*args)
+    with span("engine_wait", engine=engine):
+        jax.block_until_ready(out)
+    with span("engine_readback", engine=engine):
+        return readback(out)
 
 
 def set_enabled(on: bool) -> None:

@@ -539,7 +539,12 @@ def _um_reference_attempt(trace: Trace, run_specs: Sequence[UMSpec],
                                                 nvlink=s.nvlink))
     Cs = {k: np.asarray([[float(r[j])] for r in rows], np.float64)
           for j, (k, _) in enumerate(_COUNTER_FIELDS)}
-    return Cs, 1, dataclasses.replace(key, t_segments=1, replay=0), False
+    return (Cs, 1, dataclasses.replace(key, t_segments=1, replay=0), False,
+            None)
+
+
+def _read_counters(C) -> Dict[str, np.ndarray]:
+    return {k: np.asarray(v, np.float64) for k, v in C.items()}
 
 
 @x64_scoped
@@ -558,7 +563,8 @@ def simulate_um_many(trace: Trace, specs: Sequence[UMSpec]) -> List[UMResult]:
     for s in specs:
         _rvalidate.validate_um_spec(s)
     cache = _RESULT_CACHE.setdefault(trace, {})
-    page, n_pages = _page_stream(trace)
+    with obs.span("engine_inputs", engine="um"):
+        page, n_pages = _page_stream(trace)
     n_ph = trace.n_phases
 
     ck = _sweepckpt.active()
@@ -584,23 +590,28 @@ def simulate_um_many(trace: Trace, specs: Sequence[UMSpec]) -> List[UMResult]:
     t_rounds = None
     outcome = None
     plan = None
+    staged = None
     if run_specs:
         plan = costmodel.plan_um_split(trace.n, len(run_specs))
         t_seg = plan.t_segments
         replay = tsplit.replay_prefix() if t_seg > 1 else 0
         key = um_group_key(trace, run_specs, t_seg, replay)
-        if n_ph > 1:
-            phase = trace.phase_id
-        else:
-            phase = np.zeros((trace.n,), np.int32)
-        p = {
-            "n_pages": np.full(len(run_specs), n_pages, np.int32),
-            "n_frames": np.asarray([s.n_frames for s in run_specs], np.int32),
-            "chunk": np.asarray([s.chunk for s in run_specs], np.int32),
-            "nvlink": np.asarray([s.nvlink for s in run_specs], bool),
-            "hot_thresh": np.asarray([s.hot_thresh for s in run_specs],
-                                     np.int32),
-        }
+        with obs.span("engine_inputs", engine="um", lanes=len(run_specs)):
+            if n_ph > 1:
+                phase = trace.phase_id
+            else:
+                phase = np.zeros((trace.n,), np.int32)
+            p = {
+                "n_pages": np.full(len(run_specs), n_pages, np.int32),
+                "n_frames": np.asarray([s.n_frames for s in run_specs],
+                                       np.int32),
+                "chunk": np.asarray([s.chunk for s in run_specs], np.int32),
+                "nvlink": np.asarray([s.nvlink for s in run_specs], bool),
+                "hot_thresh": np.asarray([s.hot_thresh for s in run_specs],
+                                         np.int32),
+            }
+            xs = {"page": page, "is_write": trace.is_write.astype(bool),
+                  "phase": phase}
 
         def attempt(k: _UMKey):
             def thunk():
@@ -613,17 +624,16 @@ def simulate_um_many(trace: Trace, specs: Sequence[UMSpec]) -> List[UMResult]:
                         with obs.span("stitch", engine="um",
                                       segments=k.t_segments,
                                       replay=k.replay):
+                            kxs = _um_split_inputs(trace, k, page, phase)
                             Cs, rounds = _run_um_split(
-                                k, fn,
-                                _um_split_inputs(trace, k, page, phase),
-                                p, page, n_pages, len(run_specs))
+                                k, fn, kxs, p, page, n_pages,
+                                len(run_specs))
                     else:
-                        Cs = fn({"page": page,
-                                 "is_write": trace.is_write.astype(bool),
-                                 "phase": phase}, p)
-                        Cs = {kk: np.asarray(v, np.float64)
-                              for kk, v in Cs.items()}
-                return Cs, rounds, k, _UM_TRACE_COUNTS.get(k, 0) > before
+                        kxs = xs
+                        Cs = obs.engine_call("um", fn, (xs, p),
+                                             _read_counters)
+                return (Cs, rounds, k, _UM_TRACE_COUNTS.get(k, 0) > before,
+                        obs.staged_bytes(kxs, p))
             return thunk
 
         def bisect():
@@ -636,7 +646,7 @@ def simulate_um_many(trace: Trace, specs: Sequence[UMSpec]) -> List[UMResult]:
             Cs = {k: np.stack([np.asarray(getattr(cache[s], f), np.float64)
                                for s in run_specs])
                   for k, f in _COUNTER_FIELDS}
-            return Cs, 1, key, False
+            return Cs, 1, key, False, None
 
         rungs = [(f"T{key.t_segments}", attempt(key))]
         if key.t_segments > 1:
@@ -648,7 +658,7 @@ def simulate_um_many(trace: Trace, specs: Sequence[UMSpec]) -> List[UMResult]:
             rungs.append(
                 ("reference",
                  lambda: _um_reference_attempt(trace, run_specs, key)))
-        (Cs, t_rounds, key, compiled), outcome = _guard.run_ladder(
+        (Cs, t_rounds, key, compiled, staged), outcome = _guard.run_ladder(
             "um", rungs, bisect=bisect if len(run_specs) > 1 else None)
         if outcome.rung not in ("reference", "bisect"):
             obs.engine_run(_fingerprint(key, len(run_specs)), compiled)
@@ -671,43 +681,45 @@ def simulate_um_many(trace: Trace, specs: Sequence[UMSpec]) -> List[UMResult]:
 
     out = [cache[s] for s in specs]
     if obs.enabled():
-        obs.record(obs.RunRecord(
-            entry="simulate_um_many", engine="um", trace=trace.name,
-            n=trace.n, phases=n_ph,
-            engine_key=(_fingerprint(key, len(run_specs))
-                        if key is not None else "um:memoized"),
-            compiled=compiled, wall_s=time.perf_counter() - t_start,
-            batch=len(run_specs),
-            counter_digest=obs.counter_digest([{
-                "um_faults": r.phase_faults,
-                "um_migrated": r.phase_migrated,
-                "um_writebacks": r.phase_writebacks,
-                "um_remote_cols": r.phase_remote_cols,
-            } for r in out]),
-            t_segments=key.t_segments if key is not None else None,
-            stitch_rounds=t_rounds,
-            replay_prefix=key.replay if key is not None else None,
-            um_lanes_requested=len(specs),
-            um_lanes_run=len(run_specs),
-            um_lanes_deduped=len(specs) - len(run_specs),
-            trace_fp=_sweepckpt.trace_fingerprint(trace),
-            config_digests=[_sweepckpt.um_spec_key(r.spec) for r in out],
-            counters=[_sweepckpt.encode_counters({
-                "um_faults": r.phase_faults,
-                "um_migrated": r.phase_migrated,
-                "um_writebacks": r.phase_writebacks,
-                "um_remote_cols": r.phase_remote_cols,
-            }) for r in out],
-            ladder_rung=outcome.rung if outcome is not None else None,
-            retries=outcome.retries if outcome is not None else None,
-            degradations=(outcome.events or None)
-            if outcome is not None else None,
-            plan_predicted_us=plan.predicted_us
-            if plan is not None else None,
-            plan_alternatives=list(plan.alternatives) or None
-            if plan is not None else None,
-            calib_fingerprint=costmodel.active_profile().fingerprint,
-            host=obs.host_metadata(), **obs.git_info()))
+        with obs.span("obs_record", engine="um"):
+            obs.record(obs.RunRecord(
+                entry="simulate_um_many", engine="um", trace=trace.name,
+                n=trace.n, phases=n_ph,
+                engine_key=(_fingerprint(key, len(run_specs))
+                            if key is not None else "um:memoized"),
+                compiled=compiled, wall_s=time.perf_counter() - t_start,
+                batch=len(run_specs),
+                counter_digest=obs.counter_digest([{
+                    "um_faults": r.phase_faults,
+                    "um_migrated": r.phase_migrated,
+                    "um_writebacks": r.phase_writebacks,
+                    "um_remote_cols": r.phase_remote_cols,
+                } for r in out]),
+                t_segments=key.t_segments if key is not None else None,
+                stitch_rounds=t_rounds,
+                replay_prefix=key.replay if key is not None else None,
+                um_lanes_requested=len(specs),
+                um_lanes_run=len(run_specs),
+                um_lanes_deduped=len(specs) - len(run_specs),
+                trace_fp=_sweepckpt.trace_fingerprint(trace),
+                config_digests=[_sweepckpt.um_spec_key(r.spec) for r in out],
+                counters=[_sweepckpt.encode_counters({
+                    "um_faults": r.phase_faults,
+                    "um_migrated": r.phase_migrated,
+                    "um_writebacks": r.phase_writebacks,
+                    "um_remote_cols": r.phase_remote_cols,
+                }) for r in out],
+                ladder_rung=outcome.rung if outcome is not None else None,
+                retries=outcome.retries if outcome is not None else None,
+                degradations=(outcome.events or None)
+                if outcome is not None else None,
+                plan_predicted_us=plan.predicted_us
+                if plan is not None else None,
+                plan_alternatives=list(plan.alternatives) or None
+                if plan is not None else None,
+                calib_fingerprint=costmodel.active_profile().fingerprint,
+                input_bytes=staged,
+                host=obs.host_metadata(), **obs.git_info()))
     return out
 
 
