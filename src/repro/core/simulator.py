@@ -342,17 +342,23 @@ def _dice(n: int) -> np.ndarray:
 # which is not IEEE binary64).
 # ---------------------------------------------------------------------------
 
-def _request_stream(trace: Trace, cfg: HMSConfig,
-                    pre) -> Dict[str, np.ndarray]:
-    """The config's per-request policy inputs: the packed scan word minus
-    its shard-local row group (``meta``, int64; see :func:`_make_engine`
-    for the layout) plus the arrays :func:`_reduce_counters` reads."""
-    policy = cfg.policy
+def _score_key(cfg: HMSConfig, pre) -> tuple:
+    """Everything :func:`_score_stream` reads besides the trace: configs
+    with equal keys have equal score streams.  Keep the two in step.
+    ``pre`` enters by identity, so a key is valid only while the call that
+    holds ``pre`` runs."""
+    dram, scm = _timings(cfg)
+    return (dram.rcd, dram.wr, scm.rcd, scm.wr, float(cfg.ema_weight),
+            cfg.n_levels, bool(cfg.use_activation_counter), id(pre))
+
+
+def _score_stream(trace: Trace, cfg: HMSConfig,
+                  pre) -> Dict[str, np.ndarray]:
+    """The config's per-request bypass scores: the affinity level, the
+    level-1 filter ``pass1`` and the victim-decay dice test ``dec_ok``."""
     dram, scm = _timings(cfg)
     ncols = pre["run_ncols"]
     page_act = pre["page_act"]
-    is_write = pre["is_write"]
-    excluded = pre["amil_excluded"] & (cfg.tag_layout == "amil")
     dice = _dice(trace.n)
 
     pen = bp.scm_penalty_score(ncols, pre["run_haswrite"], dram, scm, xp=np)
@@ -374,6 +380,21 @@ def _request_stream(trace: Trace, cfg: HMSConfig,
     req_aff_lvl = bp.discretize(aff, aff_max, lv, xp=np)
     pass1 = req_lvl > avg_lvl
     dec_ok = dice < bp.p_dec(page_act, pre["max_act"], xp=np)
+    return {"req_aff_lvl": req_aff_lvl, "pass1": pass1, "dec_ok": dec_ok}
+
+
+def _pack_stream(trace: Trace, cfg: HMSConfig, pre,
+                 score: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:
+    """The config's per-request policy inputs from its score stream: the
+    packed scan word minus its shard-local row group (``meta``, int64; see
+    :func:`_make_engine` for the layout) plus the arrays
+    :func:`_reduce_counters` reads.  ``score`` may be shared with other
+    configs, so nothing here writes into it."""
+    policy = cfg.policy
+    page_act = pre["page_act"]
+    is_write = pre["is_write"]
+    excluded = pre["amil_excluded"] & (cfg.tag_layout == "amil")
+    pass1 = score["pass1"]
 
     # fill candidacy before the (stateful) accept decision
     if policy in ("hms", "no_second_level"):
@@ -381,7 +402,7 @@ def _request_stream(trace: Trace, cfg: HMSConfig,
     elif policy in ("no_bypass", "no_bypass_no_ctc", "always_cache"):
         cand = ~excluded
     elif policy == "bear":
-        cand = dice < np.float32(cfg.bear_fill_prob)
+        cand = _dice(trace.n) < np.float32(cfg.bear_fill_prob)
     elif policy == "redcache":
         cand = page_act >= np.int32(cfg.redcache_threshold)
     elif policy == "mccache":
@@ -390,13 +411,32 @@ def _request_stream(trace: Trace, cfg: HMSConfig,
         raise _rvalidate.unknown_policy_error(policy)
 
     meta = (is_write.astype(np.int64)
-            | (dec_ok.astype(np.int64) << 1)
+            | (score["dec_ok"].astype(np.int64) << 1)
             | (cand.astype(np.int64) << 2)
             | (pre["sector"].astype(np.int64) << 3)
-            | (req_aff_lvl.astype(np.int64) << 8)
+            | (score["req_aff_lvl"].astype(np.int64) << 8)
             | (pre["tag"].astype(np.int64) << 40))
     return {"meta": meta, "is_write": is_write, "excluded": excluded,
-            "pass1": pass1, "ncols": ncols}
+            "pass1": pass1, "ncols": pre["run_ncols"]}
+
+
+def _request_stream(trace: Trace, cfg: HMSConfig,
+                    pre) -> Dict[str, np.ndarray]:
+    """One config's policy inputs, scored and packed (:func:`_pack_stream`)."""
+    return _pack_stream(trace, cfg, pre, _score_stream(trace, cfg, pre))
+
+
+def _shared_request_streams(trace: Trace, cfgs: Sequence[HMSConfig], pres,
+                            keys) -> List[Dict[str, np.ndarray]]:
+    """The policy inputs of every config of one engine call, computing one
+    score stream per distinct score key (``keys[i]`` is
+    ``_score_key(cfgs[i], pres[i])``) and packing each config from it."""
+    scores = {}
+    for c, p, k in zip(cfgs, pres, keys):
+        if k not in scores:
+            scores[k] = _score_stream(trace, c, p)
+    return [_pack_stream(trace, c, p, scores[k])
+            for c, p, k in zip(cfgs, pres, keys)]
 
 
 def _reduce_counters(trace: Trace, cfg: HMSConfig, rs: Dict[str, np.ndarray],
@@ -794,7 +834,8 @@ def _obs_hms_record(entry: str, trace: Trace, key: _EngineKey, width: int,
                     rounds: int = 1, outcome=None,
                     cfgs: Sequence[HMSConfig] = (),
                     lanes: Sequence[Dict[str, np.ndarray]] = (),
-                    plan=None, input_bytes: int | None = None) -> None:
+                    plan=None, input_bytes: int | None = None,
+                    score_streams: int | None = None) -> None:
     """Build + emit one HMS ledger record (caller gates on obs.enabled()).
     ``key`` is the engine key that actually produced the counters (the
     degradation ladder may have descended from the planned one);
@@ -807,7 +848,8 @@ def _obs_hms_record(entry: str, trace: Trace, key: _EngineKey, width: int,
     :class:`~repro.core.costmodel.SplitPlan` behind the *planned* shape
     (schema 4: prediction + rejected alternatives ride the record even
     when the ladder descended).  ``input_bytes`` is what the engine call
-    staged to the device (schema 5)."""
+    staged to the device (schema 5); ``score_streams`` the distinct score
+    streams computed for its configs (schema 6)."""
     obs.record(obs.RunRecord(
         entry=entry, engine="hms", trace=trace.name, n=trace.n,
         phases=key.phases, engine_key=_fingerprint(key, width),
@@ -827,7 +869,7 @@ def _obs_hms_record(entry: str, trace: Trace, key: _EngineKey, width: int,
         plan_alternatives=list(plan.alternatives) or None
         if plan is not None else None,
         calib_fingerprint=costmodel.active_profile().fingerprint,
-        input_bytes=input_bytes,
+        input_bytes=input_bytes, score_streams=score_streams,
         host=obs.host_metadata(), **obs.git_info()))
 
 
@@ -1021,7 +1063,7 @@ def _run_hms_scan(trace: Trace, cfg: HMSConfig, pre,
                   entry: str = "simulate") -> Dict[str, np.ndarray]:
     if key is None:
         key = _engine_key(trace, cfg)
-    with obs.span("request_stream", engine="hms", configs=1):
+    with obs.span("request_stream", engine="hms", configs=1, streams=1):
         rs = _request_stream(trace, cfg, pre)
 
     def attempt(k: _EngineKey):
@@ -1069,7 +1111,9 @@ def _run_hms_scan(trace: Trace, cfg: HMSConfig, pre,
             _obs_hms_record(entry, trace, used, 1, compiled, wall,
                             obs.counter_digest(C), rounds, outcome,
                             cfgs=[cfg], lanes=[C], plan=plan,
-                            input_bytes=staged)
+                            input_bytes=staged,
+                            score_streams=None
+                            if outcome.rung == "reference" else 1)
     return C
 
 
@@ -1082,9 +1126,11 @@ def _run_hms_batch(trace: Trace, cfgs: Sequence[HMSConfig], key: _EngineKey,
     float64 per counter."""
     with obs.span("preprocess", trace=trace.name, batch=len(cfgs)):
         pres = [preprocess(trace, c) for c in cfgs]
-        with obs.span("request_stream", engine="hms", configs=len(cfgs)):
-            streams = [_request_stream(trace, c, p)
-                       for c, p in zip(cfgs, pres)]
+        keys = [_score_key(c, p) for c, p in zip(cfgs, pres)]
+        n_scores = len(set(keys))
+        with obs.span("request_stream", engine="hms", configs=len(cfgs),
+                      streams=n_scores):
+            streams = _shared_request_streams(trace, cfgs, pres, keys)
 
     def attempt(k: _EngineKey):
         def thunk():
@@ -1141,7 +1187,8 @@ def _run_hms_batch(trace: Trace, cfgs: Sequence[HMSConfig], key: _EngineKey,
         "hms_batch", rungs, bisect=bisect if len(cfgs) > 1 else None)
     wall = time.perf_counter() - t0
     plan = _PLAN_BY_KEY.get(key)
-    if outcome.rung not in ("reference", "bisect"):
+    ran = outcome.rung not in ("reference", "bisect")
+    if ran:
         obs.engine_run(_fingerprint(used, len(cfgs)), compiled)
         if plan is not None and used == key:
             costmodel.check_plan_drift(_fingerprint(used, len(cfgs)),
@@ -1153,7 +1200,8 @@ def _run_hms_batch(trace: Trace, cfgs: Sequence[HMSConfig], key: _EngineKey,
             _obs_hms_record(
                 entry, trace, used, len(cfgs), compiled, wall,
                 obs.counter_digest(lanes), rounds, outcome,
-                cfgs=cfgs, lanes=lanes, plan=plan, input_bytes=staged)
+                cfgs=cfgs, lanes=lanes, plan=plan, input_bytes=staged,
+                score_streams=n_scores if ran else None)
     return Cs
 
 

@@ -36,7 +36,12 @@ import numpy as np
 # shape, the cheapest rejected shapes, and the calibration profile that
 # priced them — next to the measured wall, so planner accuracy is a
 # query over the ledger.  Older ledgers load with them None.
-SCHEMA_VERSION = 4
+# 5: staged bytes (input_bytes): the host arrays one engine call copies to
+# the device.  Older ledgers load with it None.
+# 6: score-stream sharing (score_streams): the distinct HMS score streams
+# computed for the configs of one engine call.  Older ledgers load with it
+# None.
+SCHEMA_VERSION = 6
 
 
 def counter_digest(counters) -> str:
@@ -128,6 +133,10 @@ class RunRecord:
     # parameters, summed ``nbytes``); None where no engine ran (reference
     # rung, bisected batch, memoized UM call) and on pre-schema-5 records
     input_bytes: Optional[int] = None
+    # distinct HMS score streams computed for the call's configs (configs
+    # with equal score inputs share one); None where no engine ran and on
+    # UM records and pre-schema-6 records
+    score_streams: Optional[int] = None
     # run identity
     git_sha: Optional[str] = None
     git_dirty: Optional[bool] = None

@@ -494,3 +494,37 @@ def test_old_schema_ledger_loads_with_none_fields(tmp_path):
     (r,) = obs.load_ledger(str(tmp_path))
     assert r.trace_fp is None and r.config_digests is None \
         and r.counters is None
+
+
+@pytest.mark.parametrize("schema,dropped", [
+    (4, ("input_bytes", "score_streams")),
+    (5, ("score_streams",)),
+])
+def test_pre_schema_6_ledger_loads_and_ingests(tmp_path, schema, dropped):
+    """A schema-4 or -5 line, written before the staged bytes or the score
+    stream count existed, loads with them None and still lands its lanes
+    in the silver store."""
+    from repro.obs.store import SilverStore
+
+    lane = {k: 0.0 for k in ("demand_dram_rd", "demand_dram_wr",
+                             "demand_scm_rd", "demand_scm_wr")}
+    rec = obs.RunRecord(engine="hms", entry="simulate_many", trace="t",
+                        n=10, phases=1, engine_key="hms:hms:n10", batch=2,
+                        compiled=False, wall_s=0.1, counter_digest="0" * 16,
+                        trace_fp="f" * 16, config_digests=["a", "b"],
+                        counters=[dict(lane, demand_dram_rd=v)
+                                  for v in (1.0, 2.0)],
+                        input_bytes=64, score_streams=1)
+    assert rec.schema == 6
+    d = rec.to_dict()
+    for k in dropped:
+        d.pop(k)
+    d["schema"] = schema
+    p = tmp_path / "ledger.jsonl"
+    p.write_text(json.dumps(d) + "\n")
+    (r,) = obs.load_ledger(str(tmp_path))
+    assert r.schema == schema
+    for k in dropped:
+        assert getattr(r, k) is None, k
+    stats = SilverStore().ingest_ledger(str(p))
+    assert stats.added == 2 and stats.skipped == 0
