@@ -1483,7 +1483,10 @@ def simulate_many(trace: Trace, configs: Sequence[HMSConfig],
     footprint overflows — is prefetched through ONE batched
     ``um.simulate_um_many`` call, deduped by UM spec, so configs sharing
     (capacity, chunk, link mode) run the paging scan once for the whole
-    sweep.  Results come back in input order and match sequential
+    sweep.  Where some HMS config overflows, that call runs in a
+    ``um_overflow`` span and its run record counts the overflowing
+    configs (``overflow_points``).  Results come back in input order and
+    match sequential
     ``simulate`` counter-for-counter.
     """
     configs = [c.validate() for c in configs]
@@ -1495,17 +1498,20 @@ def simulate_many(trace: Trace, configs: Sequence[HMSConfig],
     ck = _sweepckpt.active()
     tfp = _sweepckpt.trace_fingerprint(trace) if ck is not None else None
 
-    um_specs = []
-    for cfg in configs:
-        if cfg.organization == "hbm":
-            um_specs.append(_um.um_spec(cfg, nvlink))
-        elif cfg.organization in ("hms", "separate"):
-            big = _um_overflow_config(trace, cfg)
-            if big is not None:
-                um_specs.append(_um.um_spec(big, nvlink))
-    if um_specs:
-        # warm the per-trace UM result cache in one vmapped engine call;
-        # the per-config paths below hit the memoized results
+    um_specs = [_um.um_spec(cfg, nvlink) for cfg in configs
+                if cfg.organization == "hbm"]
+    overflow = [big for cfg in configs
+                if cfg.organization in ("hms", "separate")
+                and (big := _um_overflow_config(trace, cfg)) is not None]
+    # warm the per-trace UM result cache in one vmapped engine call; the
+    # per-config paths below hit the memoized results
+    if overflow:
+        um_specs += [_um.um_spec(big, nvlink) for big in overflow]
+        with obs.span("um_overflow", points=len(overflow),
+                      specs=len(set(um_specs))):
+            _um.simulate_um_many(trace, um_specs,
+                                 overflow_points=len(overflow))
+    elif um_specs:
         _um.simulate_um_many(trace, um_specs)
 
     groups: Dict[tuple, List[int]] = {}

@@ -41,7 +41,9 @@ import numpy as np
 # 6: score-stream sharing (score_streams): the distinct HMS score streams
 # computed for the configs of one engine call.  Older ledgers load with it
 # None.
-SCHEMA_VERSION = 6
+# 7: footprint overflow (overflow_points): the HMS configs whose overflow
+# one UM call pages.  Older ledgers load with it None.
+SCHEMA_VERSION = 7
 
 
 def counter_digest(counters) -> str:
@@ -137,6 +139,10 @@ class RunRecord:
     # with equal score inputs share one); None where no engine ran and on
     # UM records and pre-schema-6 records
     score_streams: Optional[int] = None
+    # HMS configs whose footprint overflow this UM call pages (the
+    # ``simulate_many`` prefetch); None on direct UM calls, the reference
+    # rung, HMS records and pre-schema-7 records
+    overflow_points: Optional[int] = None
     # run identity
     git_sha: Optional[str] = None
     git_dirty: Optional[bool] = None

@@ -86,10 +86,14 @@ def run_um_reference(trace: Trace, cfg: HMSConfig, nvlink: bool = False):
             ev_pages = frames[ev_slot]
             ev_valid = (ev_pages >= 0) & newly      # evict one per new page
             wb_n = jnp.sum(jnp.where(ev_valid, dirty[ev_pages], False))
-            resident = resident.at[ev_pages].set(
-                jnp.where(ev_valid, False, resident[ev_pages]))
-            dirty = dirty.at[ev_pages].set(
-                jnp.where(ev_valid, False, dirty[ev_pages]))
+            # Only the evicted pages are written.  A clipped chunk at the
+            # end of the page range lists the last page more than once, so
+            # it can sit in several frames and be both an evicted victim
+            # and one that is not; writing the latter back with its old
+            # value undid the eviction in whatever order the scatter took.
+            ev_pg = jnp.where(ev_valid, ev_pages, n_pages)
+            resident = resident.at[ev_pg].set(False, mode="drop")
+            dirty = dirty.at[ev_pg].set(False, mode="drop")
             resident = resident.at[idx].set(True)
             frames = frames.at[ev_slot].set(jnp.where(newly, idx, ev_pages))
             ptr2 = ((ptr + mig_n) % n_frames).astype(jnp.int32)

@@ -548,7 +548,8 @@ def _read_counters(C) -> Dict[str, np.ndarray]:
 
 
 @x64_scoped
-def simulate_um_many(trace: Trace, specs: Sequence[UMSpec]) -> List[UMResult]:
+def simulate_um_many(trace: Trace, specs: Sequence[UMSpec], *,
+                     overflow_points: int | None = None) -> List[UMResult]:
     """Run a batch of UM configs over one trace: one compiled, vmapped scan
     for every spec not already memoized, with duplicate specs deduped to a
     single lane.  Specs whose frames cover the whole footprint early-out to
@@ -556,7 +557,9 @@ def simulate_um_many(trace: Trace, specs: Sequence[UMSpec]) -> List[UMResult]:
     degradation ladder (T>1 -> T=1 -> frozen reference where exact; OOM on
     a wide batch bisects it), and an active sweep checkpoint replays
     journaled specs from disk.  Results come back in input order and match
-    the frozen sequential reference exactly."""
+    the frozen sequential reference exactly.  ``overflow_points`` is the
+    number of HMS configs whose footprint overflow this call pages (the
+    ``simulate_many`` prefetch); it rides the call's run record."""
     global _LANES_RUN
     t_start = time.perf_counter()
     specs = list(specs)
@@ -719,6 +722,8 @@ def simulate_um_many(trace: Trace, specs: Sequence[UMSpec]) -> List[UMResult]:
                 if plan is not None else None,
                 calib_fingerprint=costmodel.active_profile().fingerprint,
                 input_bytes=staged,
+                overflow_points=overflow_points
+                if outcome is None or outcome.rung != "reference" else None,
                 host=obs.host_metadata(), **obs.git_info()))
     return out
 

@@ -497,13 +497,14 @@ def test_old_schema_ledger_loads_with_none_fields(tmp_path):
 
 
 @pytest.mark.parametrize("schema,dropped", [
-    (4, ("input_bytes", "score_streams")),
-    (5, ("score_streams",)),
+    (4, ("input_bytes", "score_streams", "overflow_points")),
+    (5, ("score_streams", "overflow_points")),
+    (6, ("overflow_points",)),
 ])
 def test_pre_schema_6_ledger_loads_and_ingests(tmp_path, schema, dropped):
-    """A schema-4 or -5 line, written before the staged bytes or the score
-    stream count existed, loads with them None and still lands its lanes
-    in the silver store."""
+    """A schema-4, -5 or -6 line, written before the staged bytes, the
+    score stream count or the overflow count existed, loads with them None
+    and still lands its lanes in the silver store."""
     from repro.obs.store import SilverStore
 
     lane = {k: 0.0 for k in ("demand_dram_rd", "demand_dram_wr",
@@ -514,8 +515,8 @@ def test_pre_schema_6_ledger_loads_and_ingests(tmp_path, schema, dropped):
                         trace_fp="f" * 16, config_digests=["a", "b"],
                         counters=[dict(lane, demand_dram_rd=v)
                                   for v in (1.0, 2.0)],
-                        input_bytes=64, score_streams=1)
-    assert rec.schema == 6
+                        input_bytes=64, score_streams=1, overflow_points=1)
+    assert rec.schema == 7
     d = rec.to_dict()
     for k in dropped:
         d.pop(k)
