@@ -26,11 +26,23 @@ model gets the same engine treatment as the HMS scan:
     so ``SimResult.phase_summary()`` UM columns are bit-for-bit consistent
     with the totals by construction.
 
+  * **One page word per page** — a lane's residency and dirtiness pack
+    into one int32 word per page, ``resident | dirty << 1``, in an array of
+    ``pages_alloc + 1`` words whose last is the dump slot.  A step gathers
+    the words of its touched set (the migration chunk, which holds the
+    request's page, and the eviction victims) once, computes every
+    decision from them, and scatters them back once, beside the victims'
+    frame scatter.
+
 Parity with the frozen sequential reference (``repro.um._reference``) is
-exact on all four outputs: the engine evaluates the same expressions with
-the same scatter/gather ordering, only with the migration chunk's lanes
-padded to the bucketed allocation (inactive lanes are routed to dump
-slots that no live index ever reads).
+exact on all four outputs: the engine evaluates the reference's decisions
+in its order (clear the evicted pages, set the chunk resident, then mark
+the request's page dirty), only with the migration chunk's lanes padded to
+the bucketed allocation (inactive lanes are routed to dump slots that no
+live index ever reads).  A page may appear in the touched set more than
+once — a clipped chunk lists its last page several times — so each slot's
+final word is computed from its page, and every duplicate writes the same
+word.
 
 Temporal splitting
 ------------------
@@ -57,6 +69,10 @@ properties make the handoff exact and fast:
     padding are gated off) and only the converged round's counters are
     kept, so all four outputs stay bit-for-bit equal to the sequential
     reference at every T.
+
+The stitch driver sees each segment's carry as ``(resident, dirty, frames,
+ptr, hotness)`` with boolean page arrays; the split engine packs them into
+page words on entry and unpacks them on exit.
 """
 
 from __future__ import annotations
@@ -222,7 +238,16 @@ def _make_um_engine(key: _UMKey):
         wlane = jnp.arange(WA, dtype=jnp.int32)
 
         def step(carry, x):
-            resident, dirty, frames, ptr, hotness = carry
+            # A lane's page state packs into one int32 word per page:
+            # resident | dirty << 1, with the dump slot DUMP = PA.  A step
+            # gathers the words of its touched set [chunk idx, victims]
+            # once, computes every decision from them, and scatters them
+            # back once: one gather + one scatter of page state per step
+            # instead of three boolean scatters, a dirty[pp] update and
+            # their gathers, and a single carry buffer XLA keeps in-place.
+            # ``hotness`` stays its own array, shared and unbatched at T=1
+            # (it depends only on the page stream).
+            pstate, frames, ptr, hotness = carry
             if split:
                 # rl: real core step (counts, increments hotness)
                 # lv: state-updates live (core steps always; replay steps
@@ -232,12 +257,40 @@ def _make_um_engine(key: _UMKey):
             else:
                 pp, w = x
                 hotness = hotness.at[pp].add(1)
-            is_res = resident[pp]
+
+            # CLOCK-flavoured eviction: 4x-chunk candidate window from the
+            # hand, coldest victims first (stable sort — pad lanes sort
+            # after every active lane, so the victim order matches the
+            # reference's window exactly).  The sort carries each
+            # candidate's frame slot and page, so the victims need no
+            # gather by the permutation (``frames`` is unchanged until its
+            # scatter).
+            wactive = wlane < 4 * mchunk
+            cand_idx = (ptr + wlane) % n_frames
+            cand_pages = frames[cand_idx]
+            hot = hotness[jnp.concatenate(
+                [pp[None], jnp.maximum(cand_pages, 0)])]
+            cand_hot = jnp.where(cand_pages >= 0, hot[1:], 0)
+            cand_hot = jnp.where(wactive, cand_hot, _HOT_PAD)
+            _, ev_slot, ev_pages = jax.lax.sort(
+                (cand_hot, cand_idx, cand_pages), num_keys=1, is_stable=True)
+            ev_slot, ev_pages = ev_slot[:CA], ev_pages[:CA]
+            ev_ok = ev_pages >= 0
+
+            # Gather the touched set's words.  The request's page is always
+            # lane pp - base of its chunk, so it needs no slot of its own.
+            base = (pp // mchunk) * mchunk
+            idx = jnp.clip(base + lane, 0, n_pages - 1).astype(jnp.int32)
+            touched = jnp.concatenate([idx, jnp.where(ev_ok, ev_pages, DUMP)])
+            word = pstate[touched]
+            res0 = (word & 1) == 1
+            pp_lane = lane == pp - base
+            is_res = jnp.any(pp_lane & res0[:CA])
 
             # Link-mode select (the reference's Python branch, as data):
             # nvlink migrates on the access counter and serves cold pages
             # remotely; fault mode migrates (and faults) on every miss.
-            hot_mig = (~is_res) & (hotness[pp] >= hot_thresh)
+            hot_mig = (~is_res) & (hot[0] >= hot_thresh)
             migrate = jnp.where(nvlink, hot_mig, ~is_res)
             remote = nvlink & (~is_res) & ~hot_mig
             if split:
@@ -245,51 +298,54 @@ def _make_um_engine(key: _UMKey):
             fault = migrate
 
             # Migration body.  The reference wraps this in lax.cond; here
-            # every lane-indexed scatter is gated instead (inactive lanes
-            # write to dump slots no live index reads), which is what cond
-            # lowers to under vmap anyway.
+            # every lane is gated instead (inactive lanes write to dump
+            # slots no live index reads), which is what cond lowers to
+            # under vmap anyway.
             active = (lane < mchunk) & migrate
-            base = (pp // mchunk) * mchunk
-            idx = jnp.clip(base + lane, 0, n_pages - 1).astype(jnp.int32)
-            newly = active & ~resident[idx]
+            newly = active & ~res0[:CA]
             mig_n = jnp.sum(newly)
+            ev_valid = ev_ok & newly                # evict one per new page
+            wb_n = jnp.sum(ev_valid & ((word[CA:] & 2) == 2))
 
-            # CLOCK-flavoured eviction: 4x-chunk candidate window from the
-            # hand, coldest victims first (stable argsort — pad lanes sort
-            # after every active lane, so the victim order matches the
-            # reference's window exactly).
-            wactive = wlane < 4 * mchunk
-            cand_idx = (ptr + wlane) % n_frames
-            cand_pages = frames[cand_idx]
-            cand_hot = jnp.where(cand_pages >= 0,
-                                 hotness[jnp.maximum(cand_pages, 0)], 0)
-            cand_hot = jnp.where(wactive, cand_hot, _HOT_PAD)
-            order = jnp.argsort(cand_hot)
-            ev_slot = cand_idx[order[:CA]]
-            ev_pages = frames[ev_slot]
-            ev_valid = (ev_pages >= 0) & newly      # evict one per new page
-            wb_n = jnp.sum(jnp.where(
-                ev_valid, dirty[jnp.maximum(ev_pages, 0)], False))
+            # Compute the final words in the reference's order: clear the
+            # evicted pages, set the chunk resident, then
+            # dirty[pp] |= w & resident[pp].  A slot's final word is a
+            # function of its page, not of its position: a clipped chunk
+            # lists its last page several times, and that page can be both
+            # an evicted victim and one that is not.  So every slot compares
+            # its page against all evicted and all migrated pages, and
+            # duplicates of one page carry one final word (the scatter's
+            # order cannot matter).
+            evicted = jnp.any(
+                touched[:, None] == jnp.where(ev_valid, ev_pages, -1)[None],
+                axis=1)
+            setres = jnp.any(
+                touched[:, None] == jnp.where(active, idx, -1)[None], axis=1)
+            res = (res0 & ~evicted) | setres
+            at_pp = (touched == pp) & w
+            if split:
+                at_pp = at_pp & lv
+            dirty = (((word & 2) == 2) & ~evicted) | (at_pp & res)
+            new_word = res.astype(jnp.int32) | (dirty.astype(jnp.int32) << 1)
 
-            ev_pg = jnp.where(ev_valid, ev_pages, DUMP)
-            resident = resident.at[ev_pg].set(False)
-            dirty = dirty.at[ev_pg].set(False)
-            resident = resident.at[jnp.where(active, idx, DUMP)].set(True)
+            # Scatter the words that can change (migrated chunk lanes, the
+            # request's page, evicted victims; the rest go to the dump
+            # slot), and the victims' frames.
+            keep_pp = (pp_lane & lv) if split else pp_lane
+            written = jnp.concatenate([active | keep_pp, ev_valid])
+            pstate = pstate.at[jnp.where(written, touched, DUMP)].set(new_word)
             frames = frames.at[jnp.where(active, ev_slot, FDUMP)].set(
                 jnp.where(newly, idx, ev_pages))
             ptr = ((ptr + mig_n) % n_frames).astype(jnp.int32)
 
             if split:
-                dpp = jnp.where(lv, pp, DUMP)
-                dirty = dirty.at[dpp].set(dirty[dpp] | (w & resident[dpp]))
                 y = (fault & rl, remote & rl,
                      jnp.where(rl, mig_n, 0).astype(jnp.int32),
                      jnp.where(rl, wb_n, 0).astype(jnp.int32))
             else:
-                dirty = dirty.at[pp].set(dirty[pp] | (w & resident[pp]))
                 y = (fault, remote,
                      mig_n.astype(jnp.int32), wb_n.astype(jnp.int32))
-            return (resident, dirty, frames, ptr, hotness), y
+            return (pstate, frames, ptr, hotness), y
 
         if split:
             rl_all = jnp.asarray(xs["real"])
@@ -302,19 +358,26 @@ def _make_um_engine(key: _UMKey):
                 return jax.lax.scan(step, c, seg_xs, unroll=4)
 
             # one vmap lane per temporal segment; each runs from its
-            # guessed boundary carry and returns it finalized
-            carry_f, (fault, remote, mig, wb) = jax.vmap(seg_scan)(
-                tuple(jnp.asarray(a) for a in carry),
-                (page, wr, rl_all, lv_all))
+            # guessed boundary carry and returns it finalized.  The stitch
+            # driver sees (resident, dirty, frames, ptr, hotness): the page
+            # word is packed on entry and unpacked on exit.
+            resident, dirty, frames, ptr, hotness = (
+                jnp.asarray(a) for a in carry)
+            pstate = (resident.astype(jnp.int32)
+                      | (dirty.astype(jnp.int32) << 1))
+            (pstate, frames, ptr, hotness), (fault, remote, mig, wb) = \
+                jax.vmap(seg_scan)((pstate, frames, ptr, hotness),
+                                   (page, wr, rl_all, lv_all))
+            carry_f = ((pstate & 1) == 1, (pstate & 2) == 2, frames, ptr,
+                       hotness)
         else:
             init = (
-                jnp.zeros((PA + 1,), jnp.bool_),
-                jnp.zeros((PA + 1,), jnp.bool_),
+                jnp.zeros((PA + 1,), jnp.int32),
                 jnp.full((FA + 1,), -1, jnp.int32),
                 jnp.zeros((), jnp.int32),
                 jnp.zeros((PA,), jnp.int32),
             )
-            carry_f, (fault, remote, mig, wb) = jax.lax.scan(
+            _, (fault, remote, mig, wb) = jax.lax.scan(
                 step, init, (page, wr), unroll=4)
 
         # Per-phase reduction (trace-order segment sums); totals are the

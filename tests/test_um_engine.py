@@ -9,7 +9,9 @@ equal to the whole-trace totals float64-bit-for-bit.
 """
 
 import dataclasses
+import re
 
+import jax
 import numpy as np
 import pytest
 
@@ -19,6 +21,7 @@ from repro.core import HMSConfig, costmodel, make_trace, simulate, \
 from repro.core.simulator import _um_overflow_config
 from repro.core.timing import COLUMN_BYTES, UM_PAGE_BYTES
 from repro.core.traces import Trace
+from repro.um import engine as um_engine
 from repro.um._reference import run_um_reference
 from repro.workloads import SCENARIOS
 
@@ -74,6 +77,99 @@ def test_reference_parity_nvlink(r_hbm):
     got = _totals(um.simulate_um(t, cfg, nvlink=True))
     assert got == tuple(float(x) for x in ref)
     assert got[3] > 0                      # remote traffic flowed
+
+
+# 34 pages (not a multiple of the 4-page chunk) in 16 frames: a fault near
+# the end migrates the chunk clipped to [32, 33, 33, 33], so page 33 sits
+# in several frames, and at one fault two victims are page 33, one evicted
+# and one not.  Every duplicate of a page must carry one final page word.
+_CLIPPED_PAGES = [
+    33, 2, 21, 15, 18, 12, 15, 7, 27, 11, 2, 6, 7, 22, 6, 29, 9, 18, 14, 4,
+    18, 31, 17, 17, 24, 28, 27, 18, 23, 7, 30, 25, 24, 26, 14, 23, 19, 8,
+    33, 18, 7, 9, 31, 25, 1, 8, 8, 5, 7, 15, 9, 3, 7, 25, 18]
+_CLIPPED_WRITES = "1111100001110111110010010101011111111101100100110001000"
+
+
+@pytest.mark.parametrize("t_seg", [1, 4], ids=["T1", "T4"])
+@pytest.mark.parametrize("nvlink", [False, True], ids=["fault", "nvlink"])
+def test_clipped_chunk_duplicate_pages_match_reference(nvlink, t_seg):
+    """A page listed several times in one step's touched set (the clipped
+    chunk's last page, as a migrated page and as evicted and kept victims)
+    gets the frozen scan's residency and dirtiness, at T=1 and split."""
+    page = np.asarray(_CLIPPED_PAGES, np.int64)
+    wr = np.asarray([c == "1" for c in _CLIPPED_WRITES])
+    fp = 34 * UM_PAGE_BYTES
+    t = Trace("clipped", page * (UM_PAGE_BYTES // COLUMN_BYTES), wr, fp)
+    cfg = HMSConfig(footprint=fp, organization="hbm", r_hbm=16 / 34)
+    spec = um.um_spec(cfg, nvlink=nvlink)
+    assert spec.n_frames == 16
+    ref = run_um_reference(t, cfg, nvlink=nvlink)
+    old_t = costmodel.set_forced_tsplit(t_seg)
+    old_r = tsplit.set_replay_prefix(0)
+    try:
+        got = _totals(um.simulate_um_many(t, [spec])[0])
+    finally:
+        costmodel.set_forced_tsplit(old_t)
+        tsplit.set_replay_prefix(old_r)
+    assert got == tuple(float(x) for x in ref)
+    assert got[1] > 0                      # the case actually migrated
+    if not nvlink:
+        assert got == (29.0, 95.0, 14.0, 0.0)
+
+
+def _hlo_computations(text):
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if m and not line.startswith(" "):
+            cur = comps.setdefault(m.group(1), [])
+        elif cur is not None:
+            cur.append(line)
+    return comps
+
+
+def _scatter_shapes(comps, name):
+    """Result shapes of every scatter run by one pass of computation
+    ``name``, following its calls (a call repeated is counted again)."""
+    out = []
+    for line in comps[name]:
+        m = re.search(r"= (\w+\[[\d,]*\])\S* scatter\(", line)
+        if m:
+            out.append(m.group(1))
+        for callee in re.findall(r" call\(.*to_apply=%?([\w.\-]+)", line):
+            out += _scatter_shapes(comps, callee)
+    return out
+
+
+def test_paging_step_keeps_one_page_word_per_lane():
+    """The T=1 engine's loop carries one int32 page word array per lane
+    (resident | dirty << 1) and no boolean page arrays, keeps the shared
+    hotness unbatched, and writes per-lane state with at most two
+    scatters a step: the page words and the victims' frames."""
+    n, lanes, unroll = 64, 2, 4
+    key = um_engine._UMKey(n=n, pages_alloc=32, frames_alloc=16,
+                           chunk_alloc=4, phases=1)
+    xs = {"page": np.zeros(n, np.int32), "is_write": np.zeros(n, bool),
+          "phase": np.zeros(n, np.int32)}
+    p = {"n_pages": np.full(lanes, 30, np.int32),
+         "n_frames": np.full(lanes, 16, np.int32),
+         "chunk": np.full(lanes, 4, np.int32),
+         "nvlink": np.zeros(lanes, bool),
+         "hot_thresh": np.zeros(lanes, np.int32)}
+    with jax.enable_x64(True):
+        text = um_engine._engine_for(key).lower(xs, p).as_text(dialect="hlo")
+    (loop,) = [ln for ln in text.splitlines() if " while(" in ln]
+    carry = loop.split(" while(")[0]
+    assert carry.count("s32[2,33]") == 1            # page words, dump slot
+    assert "pred[2,33]" not in carry                # no resident / dirty
+    assert carry.count("s32[2,17]") == 1            # frames
+    assert re.search(r"s32\[32\]\S*[,)]", carry)    # hotness, unbatched
+    body = re.search(r"body=%?([\w.\-]+)", loop).group(1)
+    shapes = _scatter_shapes(_hlo_computations(text), body)
+    lane_scatters = [s for s in shapes if re.match(rf"\w+\[{lanes},", s)]
+    assert sorted(set(lane_scatters)) == ["s32[2,17]", "s32[2,33]"]
+    assert len(lane_scatters) / unroll <= 2
+    assert set(shapes) - set(lane_scatters) == {"s32[32]"}  # hotness += 1
 
 
 def test_early_out_when_frames_cover_pages():
