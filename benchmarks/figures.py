@@ -275,8 +275,7 @@ def sweep_design_space(results: Dict) -> List[tuple]:
     from repro import obs
     from repro.core import HMSConfig, costmodel, simulate, simulate_many
     from repro.core import tsplit as tsplit_mod
-    from repro.core.simulator import (_engine_key, group_engine_key,
-                                      set_max_shards)
+    from repro.core.simulator import group_engine_key
     from repro.resilience import sweepckpt as _sweepckpt
 
     from .common import (bench_n, host_metadata, register_partial, trace,
@@ -318,7 +317,7 @@ def sweep_design_space(results: Dict) -> List[tuple]:
                 for kw in grid]
         base = HMSConfig(footprint=t.footprint).validate()
         gkey = group_engine_key(t, cfgs)
-        skey = _engine_key(t, base)
+        skey = group_engine_key(t, [base])
 
         # full grid: cold (compile + run) then steady-state (best of 2 —
         # single timed calls are noisy on small shared hosts)
@@ -327,13 +326,13 @@ def sweep_design_space(results: Dict) -> List[tuple]:
         # single config: auto shards vs forced sequential scan
         _, _ = timed(lambda: simulate(t, base))
         _, single_s = timed(lambda: simulate(t, base), reps=2)
-        old = set_max_shards(1)
+        old = costmodel.set_max_shards(1)
         try:
             _, _ = timed(lambda: simulate(t, base))
             _, single_s1_s = timed(lambda: simulate(t, base), reps=2)
             _, grid_s1_s = timed(lambda: simulate_many(t, cfgs), reps=2)
         finally:
-            set_max_shards(old)
+            costmodel.set_max_shards(old)
 
         bi = min(range(len(rs)), key=lambda i: rs[i].runtime_cycles)
         bkw = grid[bi]
@@ -385,7 +384,7 @@ def sweep_design_space(results: Dict) -> List[tuple]:
     was_enabled = obs.enabled()
     if not was_enabled:
         obs.enable()                       # in-memory: stitch_rounds per T
-    old_cap = set_max_shards(1)
+    old_cap = costmodel.set_max_shards(1)
     curve = {}
     try:
         for tv in t_grid:
@@ -404,7 +403,7 @@ def sweep_design_space(results: Dict) -> List[tuple]:
                 costmodel.set_forced_tsplit(old_t)
                 tsplit_mod.set_replay_prefix(old_r)
     finally:
-        set_max_shards(old_cap)
+        costmodel.set_max_shards(old_cap)
         if not was_enabled:
             obs.disable()
     digests = {c["counter_digest"] for c in curve.values()}

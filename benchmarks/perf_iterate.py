@@ -69,9 +69,9 @@ def hms_sweep(args):
     import time
 
     from repro import obs
-    from repro.core import HMSConfig, make_trace, simulate, simulate_many
-    from repro.core.simulator import (engine_trace_count, group_engine_key,
-                                      set_max_shards)
+    from repro.core import (HMSConfig, costmodel, make_trace, simulate,
+                            simulate_many)
+    from repro.core.simulator import engine_trace_count, group_engine_key
 
     t = make_trace(args.workload, n=args.n)
     grid = [{"tag_layout": lay, "ctc_fraction": frac, "scm_mode": mode}
@@ -101,14 +101,14 @@ def hms_sweep(args):
     t0 = time.time()
     simulate(t, base)
     out["single_auto_s"] = time.time() - t0
-    old = set_max_shards(1)
+    old = costmodel.set_max_shards(1)
     try:
         simulate(t, base)
         t0 = time.time()
         simulate(t, base)
         out["single_s1_s"] = time.time() - t0
     finally:
-        set_max_shards(old)
+        costmodel.set_max_shards(old)
     out["shards"] = key.shards
     out["shard_speedup"] = out["single_s1_s"] / max(out["single_auto_s"], 1e-9)
     print(f"hms-sweep {args.workload} n={args.n} points={len(grid)}: "

@@ -58,8 +58,9 @@ split so a sweep costs one compile and one short device loop:
     exactly — parity with the sequential formulation is bit-for-bit because
     every slot and CTC set still sees its original request subsequence in
     order.  ``S`` is chosen per engine key (capped by ``REPRO_SHARDS`` /
-    :func:`set_max_shards`, shard depth, and the CTC set counts of every
-    config sharing the compile); S=1 reproduces the PR 2 sequential engine.
+    :func:`repro.core.costmodel.set_max_shards`, shard depth, and the CTC
+    set counts of every config sharing the compile); S=1 is the sequential
+    engine.
   * **Temporal splitting** — when spatial lanes run out (zipf traces whose
     hottest CTC set bounds the LPT depth at low S), each shard's stream is
     further cut into T *temporal segments* run as extra vmap lanes, each
@@ -75,7 +76,8 @@ split so a sweep costs one compile and one short device loop:
   * ``simulate_many`` vmaps the compiled engine over a batch of runtime
     parameter sets sharing one static structure, so Fig. 18-style CTC
     sweeps and policy ablations cost one compile + one device loop over
-    ``configs x shards``.
+    ``configs x shards``.  ``simulate`` runs its config as a batch of one
+    through the same call and the same compiled engine.
   * The **Unified-Memory baseline** (oversubscribed HBM + page migration,
     and the HMS overflow path) lives in ``repro.um`` — the same
     compile-once treatment for the paging scan: bucketed page/frame
@@ -249,30 +251,6 @@ class _EngineKey:
 
 _USES_CTC = POLICIES_WITH_CTC
 
-# The scan-step cost constants and shard/segment caps live in
-# ``repro.core.costmodel`` (one model for both engines); these delegations
-# keep the long-standing public override points on this module.
-
-
-def set_max_shards(cap: int) -> int:
-    """Set the shard-count cap (1 = sequential engine); returns the old cap.
-    Benchmarks use this to measure shard speedup against the S=1 scan.
-    Delegates to :func:`repro.core.costmodel.set_max_shards`."""
-    return costmodel.set_max_shards(cap)
-
-
-def set_forced_shards(n: int | None) -> int | None:
-    """Pin the shard count, bypassing the cost model (any count is valid —
-    set bins just go empty past the partition-domain size).  Tests use this
-    so shard-parallel coverage doesn't depend on host-tuned cost constants.
-    ``None`` restores automatic selection; returns the previous value.
-    Delegates to :func:`repro.core.costmodel.set_forced_shards`."""
-    return costmodel.set_forced_shards(n)
-
-
-def _engine_key(trace: Trace, cfg: HMSConfig) -> _EngineKey:
-    return group_engine_key(trace, [cfg])
-
 
 def _runtime_params(cfg: HMSConfig,
                     n_sets_local: int = -1) -> Dict[str, np.ndarray]:
@@ -280,7 +258,7 @@ def _runtime_params(cfg: HMSConfig,
     compiled scan.  ``n_sets_local`` is the *shard-local* CTC set count from
     the shard plan (the sets of one config partition across its shards).
     Every other config field acts on the host side
-    (:func:`_request_stream`, :func:`_reduce_counters`)."""
+    (:func:`_score_stream`, :func:`_pack_stream`, :func:`_reduce_counters`)."""
     return {
         "ctc_ways": np.int32(cfg.ctc_ways),
         "ctc_sets": np.int32(cfg.ctc_sets if n_sets_local < 0
@@ -418,12 +396,6 @@ def _pack_stream(trace: Trace, cfg: HMSConfig, pre,
             | (pre["tag"].astype(np.int64) << 40))
     return {"meta": meta, "is_write": is_write, "excluded": excluded,
             "pass1": pass1, "ncols": pre["run_ncols"]}
-
-
-def _request_stream(trace: Trace, cfg: HMSConfig,
-                    pre) -> Dict[str, np.ndarray]:
-    """One config's policy inputs, scored and packed (:func:`_pack_stream`)."""
-    return _pack_stream(trace, cfg, pre, _score_stream(trace, cfg, pre))
 
 
 def _shared_request_streams(trace: Trace, cfgs: Sequence[HMSConfig], pres,
@@ -757,12 +729,14 @@ def _make_engine(key: _EngineKey):
     return engine
 
 
-# Module-level jit caches: one compiled engine per static structure, plus a
-# per-batch-width vmapped variant.  ``_TRACE_COUNTS`` counts Python traces of
-# each engine (a retrace executes the Python body), which the no-retrace test
-# asserts on.
+# Module-level jit cache: one compiled engine per static structure, taking a
+# leading batch axis (jit re-specializes per batch width on its own).
+# ``_TRACE_COUNTS`` counts Python traces of each engine (a retrace executes
+# the Python body), which the no-retrace test asserts on.
 _ENGINE_CACHE: Dict[_EngineKey, object] = {}
-_BATCHED_CACHE: Dict[_EngineKey, object] = {}
+# the chip benchmark's ``fresh_engines`` fixture
+# (tests/chipbench/test_chipbench_checks.py) still patches this name
+_BATCHED_CACHE = _ENGINE_CACHE
 _TRACE_COUNTS: Dict[_EngineKey, int] = {}
 
 
@@ -772,10 +746,10 @@ def engine_trace_count(key: _EngineKey) -> int:
 
 
 def group_engine_key(trace: Trace, configs: Sequence[HMSConfig]) -> _EngineKey:
-    """The engine key ``simulate_many`` uses for a batch of scan configs
-    (shard count and allocations are group-wide, so this can differ from any
-    single config's ``_engine_key``).  Shard plans and allocations derive
-    from cached per-config preprocessing."""
+    """The engine key of one batch of scan configs (shard count and
+    allocations are group-wide, so a config's key alone,
+    ``group_engine_key(trace, [cfg])``, can differ).  Shard plans and
+    allocations derive from cached per-config preprocessing."""
     cfgs = [c.validate() for c in configs]
     policies = {c.policy for c in cfgs}
     sectors = {c.ctc_sectors_per_line for c in cfgs}
@@ -821,8 +795,8 @@ _PLAN_BY_KEY: Dict[_EngineKey, costmodel.SplitPlan] = {}
 
 def _fingerprint(key: _EngineKey, width: int) -> str:
     """Sentinel/ledger fingerprint of one compiled unit: the static engine
-    key plus the vmap batch width (the batched jit re-specializes per
-    width, so width is part of what 'one compile' means)."""
+    key plus the batch width (the jit re-specializes per width, so width
+    is part of what 'one compile' means)."""
     return (f"hms:{key.policy}:n{key.n}:s{key.shards}x{key.depth}"
             f":T{key.t_segments}r{key.replay}"
             f":L{key.lines_alloc}:C{key.ctc_sets_alloc}x{key.ctc_ways_alloc}"
@@ -887,26 +861,31 @@ def _counting(key: _EngineKey):
 
 
 def _engine_for(key: _EngineKey):
-    if key not in _ENGINE_CACHE:
-        _ENGINE_CACHE[key] = jax.jit(_counting(key))
-    return _ENGINE_CACHE[key]
-
-
-def _batched_engine_for(key: _EngineKey):
     # Stacked xs (in_axes=0 everywhere) costs batch-width host copies of the
     # trace arrays but runs ~3x faster than broadcasting shared arrays with
     # in_axes=None: the vmapped scan slices uniform batched xs contiguously
     # per step, while broadcast operands re-materialize inside the loop.
     # jit re-specializes per batch shape on its own, so the key needs no
     # width component.
-    if key not in _BATCHED_CACHE:
-        if key.t_segments > 1:
-            # per-config xs/params/carries; the replay flag is shared
-            vmapped = jax.vmap(_counting(key), in_axes=(0, 0, 0, None))
-        else:
-            vmapped = jax.vmap(_counting(key))
-        _BATCHED_CACHE[key] = jax.jit(vmapped)
-    return _BATCHED_CACHE[key]
+    if key not in _ENGINE_CACHE:
+        base = _counting(key)
+        split = key.t_segments > 1
+        # per-config xs/params/carries; the replay flag is shared
+        vmapped = jax.vmap(base, in_axes=(0, 0, 0, None) if split else 0)
+        batched = 3 if split else 2
+
+        def fn(*args):
+            if args[0]["pos"].shape[0] > 1:
+                return vmapped(*args)
+            # a batch of one runs the body unbatched: vmap would keep its
+            # size-1 axis in the scan's loop (6 more fusions and 3 more
+            # copies a step; 6.09 against 4.63 us a step on a TPU v5e)
+            one = jax.tree_util.tree_map(lambda a: a[0], args[:batched])
+            out = base(*one, *args[batched:])
+            return jax.tree_util.tree_map(lambda a: a[None], out)
+
+        _ENGINE_CACHE[key] = jax.jit(fn)
+    return _ENGINE_CACHE[key]
 
 
 def _local_sets(trace: Trace, cfg: HMSConfig, key: _EngineKey) -> int:
@@ -952,20 +931,19 @@ def _stitch_masks(trace: Trace, cfg: HMSConfig, key: _EngineKey):
 def _run_split(key: _EngineKey, fn, xs, params, masks):
     """Drive a T>1 engine to its exact fixed point (see ``repro.core.tsplit``).
 
-    ``masks`` are the per-config touched masks from :func:`_stitch_masks`,
-    with a leading batch axis when ``fn`` is the batched engine.  Returns
-    ``(decision_words, total_rounds)`` — words from the converged round
-    only, so they are bit-for-bit the sequential scan's."""
+    ``masks`` are the touched masks from :func:`_stitch_masks`, stacked
+    over the batch's configs.  Returns ``(decision_words, total_rounds)`` —
+    words from the converged round only, so they are bit-for-bit the
+    sequential scan's."""
     slot_m, set_m = masks
     S, T = key.shards, key.t_segments
-    lanes = S * T
-    lead = slot_m.shape[:-3]                     # () or (batch,)
+    B = slot_m.shape[0]
     ctc_row = np.asarray(ctc_mod.packed_init(
         key.ctc_sets_alloc, key.ctc_ways_alloc, key.ctc_sectors))
-    cache0 = np.zeros(lead + (lanes, key.lines_alloc), np.int32)
-    ctc0 = np.broadcast_to(ctc_row, lead + (lanes,) + ctc_row.shape).copy()
-    seg_c = lead + (S, T, key.lines_alloc)
-    seg_t = lead + (S, T) + ctc_row.shape
+    cache0 = np.zeros((B, S * T, key.lines_alloc), np.int32)
+    ctc0 = np.broadcast_to(ctc_row, (B, S * T) + ctc_row.shape).copy()
+    seg_c = (B, S, T, key.lines_alloc)
+    seg_t = (B, S, T) + ctc_row.shape
 
     def run(g, use_replay):
         (cache_f, ctc_f), y = fn(xs, params, g, np.bool_(use_replay))
@@ -1046,84 +1024,21 @@ def _hms_reference_attempt(trace: Trace, cfgs: Sequence[HMSConfig],
     """Last ladder rung: the frozen seed engine.  It returns whole-trace
     totals only (no per-phase vectors), so the ladder offers it for
     unphased traces — where its counters are pinned bit-equal to the
-    batched engine's by ``tests/test_engine_parity.py``."""
+    engine's by ``tests/test_engine_parity.py``."""
     from . import _reference
     label = dataclasses.replace(key, shards=1, t_segments=1, replay=0)
     per = [_reference.reference_counters(trace, c) for c in cfgs]
-    if len(cfgs) == 1:
-        C = {k: np.float64(v) for k, v in per[0].items()}
-    else:
-        C = {k: np.asarray([d[k] for d in per], np.float64)
-             for k in per[0]}
+    C = {k: np.asarray([d[k] for d in per], np.float64) for k in per[0]}
     return C, 1, label, False, None
-
-
-def _run_hms_scan(trace: Trace, cfg: HMSConfig, pre,
-                  key: _EngineKey | None = None,
-                  entry: str = "simulate") -> Dict[str, np.ndarray]:
-    if key is None:
-        key = _engine_key(trace, cfg)
-    with obs.span("request_stream", engine="hms", configs=1, streams=1):
-        rs = _request_stream(trace, cfg, pre)
-
-    def attempt(k: _EngineKey):
-        def thunk():
-            with obs.span("engine_inputs", engine="hms", batch=1):
-                xs = _engine_inputs(trace, cfg, pre, rs, k)
-                params = _runtime_params(cfg, _local_sets(trace, cfg, k))
-            fn = _engine_for(k)
-            before = _TRACE_COUNTS.get(k, 0)
-            rounds = 1
-            with obs.span("scan", engine="hms", policy=k.policy,
-                          shards=k.shards, batch=1):
-                if k.t_segments > 1:
-                    with obs.span("stitch", engine="hms",
-                                  segments=k.t_segments, replay=k.replay):
-                        masks = _stitch_masks(trace, cfg, k)
-                        y, rounds = _run_split(k, fn, xs, params, masks)
-                else:
-                    y = obs.engine_call("hms", fn, (xs, params), np.asarray)
-            # scalar (unphased) or (n_phases,) vector per counter
-            with obs.span("reduce_counters", engine="hms", batch=1):
-                C = _reduce_counters(trace, cfg, rs, y)
-            return (C, rounds, k, _TRACE_COUNTS.get(k, 0) > before,
-                    obs.staged_bytes(xs, params))
-        return thunk
-
-    rungs = [(f"S{k.shards}T{k.t_segments}", attempt(k))
-             for k in _hms_ladder_keys(trace, [cfg], key)]
-    if key.phases == 1:
-        rungs.append(
-            ("reference",
-             lambda: _hms_reference_attempt(trace, [cfg], key)))
-    t0 = time.perf_counter()
-    (C, rounds, used, compiled, staged), outcome = _guard.run_ladder(
-        "hms", rungs)
-    wall = time.perf_counter() - t0
-    plan = _PLAN_BY_KEY.get(key)
-    if outcome.rung != "reference":
-        obs.engine_run(_fingerprint(used, 1), compiled)
-        if plan is not None and used == key:
-            costmodel.check_plan_drift(_fingerprint(used, 1),
-                                       plan.predicted_us, wall, compiled)
-    if obs.enabled():
-        with obs.span("obs_record", engine="hms"):
-            _obs_hms_record(entry, trace, used, 1, compiled, wall,
-                            obs.counter_digest(C), rounds, outcome,
-                            cfgs=[cfg], lanes=[C], plan=plan,
-                            input_bytes=staged,
-                            score_streams=None
-                            if outcome.rung == "reference" else 1)
-    return C
 
 
 def _run_hms_batch(trace: Trace, cfgs: Sequence[HMSConfig], key: _EngineKey,
                    entry: str = "simulate_many") -> Dict[str, np.ndarray]:
-    """Run one compatible config group through the batched engine (with the
-    temporal-split stitch when the key says so), under the degradation
-    ladder — an OOM on the whole batch bisects into guarded halves.
-    Returns the stacked counter dict: ``(batch,)`` or ``(batch, phases)``
-    float64 per counter."""
+    """Run one compatible config group, one config or many, through the
+    engine (with the temporal-split stitch when the key says so), under the
+    degradation ladder — an OOM on a batch of more than one bisects into
+    guarded halves.  Returns the stacked counter dict: ``(batch,)`` or
+    ``(batch, phases)`` float64 per counter."""
     with obs.span("preprocess", trace=trace.name, batch=len(cfgs)):
         pres = [preprocess(trace, c) for c in cfgs]
         keys = [_score_key(c, p) for c, p in zip(cfgs, pres)]
@@ -1143,7 +1058,7 @@ def _run_hms_batch(trace: Trace, cfgs: Sequence[HMSConfig], key: _EngineKey,
                                for c in cfgs]
                 params = {kk: np.stack([p[kk] for p in params_list])
                           for kk in params_list[0]}
-            fn = _batched_engine_for(k)
+            fn = _engine_for(k)
             before = _TRACE_COUNTS.get(k, 0)
             rounds = 1
             with obs.span("scan", engine="hms", policy=k.policy,
@@ -1184,7 +1099,7 @@ def _run_hms_batch(trace: Trace, cfgs: Sequence[HMSConfig], key: _EngineKey,
              lambda: _hms_reference_attempt(trace, cfgs, key)))
     t0 = time.perf_counter()
     (Cs, rounds, used, compiled, staged), outcome = _guard.run_ladder(
-        "hms_batch", rungs, bisect=bisect if len(cfgs) > 1 else None)
+        "hms", rungs, bisect=bisect if len(cfgs) > 1 else None)
     wall = time.perf_counter() - t0
     plan = _PLAN_BY_KEY.get(key)
     ran = outcome.rung not in ("reference", "bisect")
@@ -1203,6 +1118,11 @@ def _run_hms_batch(trace: Trace, cfgs: Sequence[HMSConfig], key: _EngineKey,
                 cfgs=cfgs, lanes=lanes, plan=plan, input_bytes=staged,
                 score_streams=n_scores if ran else None)
     return Cs
+
+
+def _lane(Cs: Dict[str, np.ndarray], j: int) -> Dict[str, np.ndarray]:
+    """Config ``j``'s counters from :func:`_run_hms_batch`'s stacked dict."""
+    return {k: np.asarray(v[j], np.float64) for k, v in Cs.items()}
 
 
 # ---------------------------------------------------------------------------
@@ -1462,12 +1382,10 @@ def _simulate(trace: Trace, cfg: HMSConfig, nvlink: bool,
         return _finish(trace.name, cfg, C, n_requests=trace.n,
                        phase_names=trace.phase_names)
 
-    # hms / separate
-    with obs.span("preprocess", trace=trace.name):
-        pre = preprocess(trace, cfg)
-    C = _run_hms_scan(trace, cfg, pre, entry=entry)
+    # hms / separate: a batch of one
+    Cs = _run_hms_batch(trace, [cfg], group_engine_key(trace, [cfg]), entry)
     with obs.span("postprocess", trace=trace.name):
-        return _finish_hms(trace, cfg, C, nvlink)
+        return _finish_hms(trace, cfg, _lane(Cs, 0), nvlink)
 
 
 @x64_scoped
@@ -1534,21 +1452,11 @@ def simulate_many(trace: Trace, configs: Sequence[HMSConfig],
             idxs = pend
             if not idxs:
                 continue
-        key = group_engine_key(trace, [configs[i] for i in idxs])
-        if len(idxs) == 1:
-            i = idxs[0]
-            C = _run_hms_scan(trace, configs[i],
-                              preprocess(trace, configs[i]), key,
-                              entry="simulate_many")
-            if ck is not None:
-                ck.put_hms(tfp, configs[i], nvlink, C)
-            results[i] = _finish_hms(trace, configs[i], C, nvlink)
-            continue
-        Cs = _run_hms_batch(trace, [configs[i] for i in idxs], key)
+        cfgs = [configs[i] for i in idxs]
+        Cs = _run_hms_batch(trace, cfgs, group_engine_key(trace, cfgs))
         with obs.span("postprocess", trace=trace.name, batch=len(idxs)):
             for j, i in enumerate(idxs):
-                C = {k: np.asarray(v[j], np.float64)
-                     for k, v in Cs.items()}
+                C = _lane(Cs, j)
                 if ck is not None:
                     # journal before finishing, so a kill mid-batch keeps
                     # every lane the engine already produced

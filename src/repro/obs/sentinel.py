@@ -63,19 +63,18 @@ def _forget(prefix: str) -> None:
 def cache_stats() -> Dict[str, int]:
     """One view over both engines' jit caches and the run accounting:
 
-    ``hms_engines`` / ``hms_batched_engines``  compiled HMS entries
-    ``hms_traces``                             total HMS Python traces
-    ``um_engines`` / ``um_traces``             same for the paging engine
-    ``um_results_cached``                      memoized UM results (all traces)
-    ``um_lanes_run``                           cumulative engine lanes executed
-    ``engine_runs`` / ``engine_compiles``      sentinel totals since reset()
+    ``hms_engines``                        compiled HMS entries
+    ``hms_traces``                         total HMS Python traces
+    ``um_engines`` / ``um_traces``         same for the paging engine
+    ``um_results_cached``                  memoized UM results (all traces)
+    ``um_lanes_run``                       cumulative engine lanes executed
+    ``engine_runs`` / ``engine_compiles``  sentinel totals since reset()
     """
     from repro.core import simulator as _sim
     from repro.um import engine as _um
 
     return {
         "hms_engines": len(_sim._ENGINE_CACHE),
-        "hms_batched_engines": len(_sim._BATCHED_CACHE),
         "hms_traces": sum(_sim._TRACE_COUNTS.values()),
         "um_engines": len(_um._UM_ENGINE_CACHE),
         "um_traces": sum(_um._UM_TRACE_COUNTS.values()),
@@ -109,7 +108,6 @@ def reset(*, hms: bool = True, um: bool = True,
             _forget("um:")
     if hms and not keep_compiled:
         _sim._ENGINE_CACHE.clear()
-        _sim._BATCHED_CACHE.clear()
         _sim._TRACE_COUNTS.clear()
         _forget("hms:")
 

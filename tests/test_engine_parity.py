@@ -7,6 +7,7 @@ whether configs run sequentially or vmapped through ``simulate_many``.
 """
 
 import dataclasses
+import re
 
 import numpy as np
 import pytest
@@ -14,8 +15,8 @@ import pytest
 from repro import obs
 from repro.core import HMSConfig, costmodel, simulate, simulate_many, tsplit
 from repro.core._reference import reference_counters
-from repro.core.simulator import (_COUNTERS, _engine_key, engine_trace_count,
-                                  set_forced_shards, set_max_shards)
+from repro.core.simulator import (_COUNTERS, engine_trace_count,
+                                  group_engine_key)
 from repro.core.traces import Trace
 
 
@@ -63,7 +64,7 @@ def test_runtime_scalar_sweep_compiles_once():
     """Configs differing only in runtime scalars share one compiled engine."""
     t = _golden_trace()
     base = HMSConfig(footprint=t.footprint).validate()
-    key = _engine_key(t, base)
+    key = group_engine_key(t, [base])
     simulate(t, base)
     warm = engine_trace_count(key)
     assert warm >= 1
@@ -80,10 +81,84 @@ def test_runtime_scalar_sweep_compiles_once():
     with obs.assert_no_retrace():      # key is warm at entry
         for kw in sweeps:
             cfg = dataclasses.replace(base, **kw).validate()
-            assert _engine_key(t, cfg) == key, f"{kw} changed the static key"
+            assert group_engine_key(t, [cfg]) == key, (
+                f"{kw} changed the static key")
             simulate(t, cfg)
     assert engine_trace_count(key) == warm, (
         "runtime-scalar sweep re-traced the engine")
+
+
+def test_simulate_and_a_batch_of_one_share_one_engine():
+    """``simulate`` runs its config as ``simulate_many`` runs a group of
+    one: the same compiled engine, the same record shape, equal counters."""
+    t = _golden_trace(seed=29)
+    cfg = HMSConfig(footprint=t.footprint).validate()
+    key = group_engine_key(t, [cfg])
+    obs.reset(um=False)
+    obs.clear_records()
+    obs.enable()
+    try:
+        single = simulate(t, cfg)
+        (many,) = simulate_many(t, [cfg])
+        recs = [r for r in obs.records() if r.engine == "hms"]
+    finally:
+        obs.disable()
+        obs.clear_records()
+    assert engine_trace_count(key) == 1
+    assert obs.cache_stats()["hms_engines"] == 1
+    assert [(r.entry, r.batch) for r in recs] == [("simulate", 1),
+                                                   ("simulate_many", 1)]
+    assert recs[0].engine_key == recs[1].engine_key
+    assert recs[0].engine_key.endswith(":w1")
+    assert recs[0].counter_digest == recs[1].counter_digest
+    assert set(single.counters) == set(many.counters) == set(_COUNTERS)
+    for k in _COUNTERS:
+        np.testing.assert_array_equal(many.counters[k], single.counters[k],
+                                      err_msg=k)
+
+
+@pytest.mark.parametrize("t_seg", [1, 2], ids=["T1", "T2"])
+def test_batch_of_one_scans_without_a_lane_axis(t_seg):
+    """A width-1 call loops over the same state as the engine body run
+    alone: no size-1 lane axis in the scan's carry."""
+    import jax
+
+    from repro.core import ctc
+    from repro.core import simulator as sim
+    from repro.core.traces import preprocess
+
+    t = _golden_trace(seed=31)
+    cfg = HMSConfig(footprint=t.footprint).validate()
+    old_s = costmodel.set_forced_shards(2)
+    old_t = costmodel.set_forced_tsplit(t_seg)
+    try:
+        key = group_engine_key(t, [cfg])
+    finally:
+        costmodel.set_forced_shards(old_s)
+        costmodel.set_forced_tsplit(old_t)
+    assert key.t_segments == t_seg
+    with jax.enable_x64(True):
+        pre = preprocess(t, cfg)
+        rs = sim._pack_stream(t, cfg, pre, sim._score_stream(t, cfg, pre))
+        args = [sim._engine_inputs(t, cfg, pre, rs, key),
+                sim._runtime_params(cfg, sim._local_sets(t, cfg, key))]
+        if t_seg > 1:
+            lanes = key.shards * key.t_segments
+            row = np.asarray(ctc.packed_init(
+                key.ctc_sets_alloc, key.ctc_ways_alloc, key.ctc_sectors))
+            args += [(np.zeros((lanes, key.lines_alloc), np.int32),
+                      np.broadcast_to(row, (lanes,) + row.shape).copy()),
+                     np.bool_(False)]
+        one = [jax.tree_util.tree_map(lambda x: np.stack([x]), arg)
+               for arg in args[:3]] + args[3:]
+
+        def loops(lowered):
+            text = lowered.as_text(dialect="hlo")
+            return re.findall(r"= (\(.*?\)) while\(", text)
+
+        alone = loops(jax.jit(sim._make_engine(key)).lower(*args))
+        width1 = loops(sim._engine_for(key).lower(*one))
+    assert alone and width1 == alone
 
 
 def test_simulate_many_matches_sequential():
@@ -138,13 +213,13 @@ def test_shard_parallel_parity_vs_reference(kw):
     # small r_hbm -> small DRAM cache -> deep tag aliasing, and a CTC with
     # multiple sets so the hms policy distributes across shards too
     cfg = HMSConfig(footprint=t.footprint, r_hbm=0.1, **kw)
-    old = set_forced_shards(4)
+    old = costmodel.set_forced_shards(4)
     try:
-        key = _engine_key(t, cfg)
+        key = group_engine_key(t, [cfg])
         assert key.shards == 4
         new = simulate(t, cfg).counters
     finally:
-        set_forced_shards(old)
+        costmodel.set_forced_shards(old)
     ref = reference_counters(t, cfg)
     for k in _COUNTERS:
         np.testing.assert_allclose(new[k], ref[k], rtol=1e-9, atol=1e-6,
@@ -158,18 +233,18 @@ def test_shard_engine_matches_sequential_scan():
 
     t = make_trace("bfs_tu", n=30_000)
     cfg = HMSConfig(footprint=t.footprint)
-    old = set_forced_shards(8)
+    old = costmodel.set_forced_shards(8)
     try:
-        assert _engine_key(t, cfg).shards == 8
+        assert group_engine_key(t, [cfg]).shards == 8
         sharded = simulate(t, cfg).counters
     finally:
-        set_forced_shards(old)
-    old_cap = set_max_shards(1)
+        costmodel.set_forced_shards(old)
+    old_cap = costmodel.set_max_shards(1)
     try:
-        assert _engine_key(t, cfg).shards == 1
+        assert group_engine_key(t, [cfg]).shards == 1
         seq = simulate(t, cfg).counters
     finally:
-        set_max_shards(old_cap)
+        costmodel.set_max_shards(old_cap)
     for k in _COUNTERS:
         np.testing.assert_allclose(sharded[k], seq[k], rtol=1e-12, atol=0,
                                    err_msg=f"shard-parallel diverged on {k}")
@@ -189,15 +264,15 @@ def test_temporal_split_parity_vs_reference(s, t_seg, replay):
     t = _aliasing_trace()
     cfg = HMSConfig(footprint=t.footprint, r_hbm=0.1)
     ref = reference_counters(t, cfg)
-    old_s = set_forced_shards(s)
+    old_s = costmodel.set_forced_shards(s)
     old_t = costmodel.set_forced_tsplit(t_seg)
     old_r = tsplit.set_replay_prefix(replay)
     try:
-        key = _engine_key(t, cfg)
+        key = group_engine_key(t, [cfg])
         assert key.shards == s and key.t_segments == t_seg
         new = simulate(t, cfg).counters
     finally:
-        set_forced_shards(old_s)
+        costmodel.set_forced_shards(old_s)
         costmodel.set_forced_tsplit(old_t)
         tsplit.set_replay_prefix(old_r)
     for k in _COUNTERS:
@@ -216,20 +291,20 @@ def test_temporal_split_matches_unsplit(kw):
     stitch only terminates at an exact fixed point."""
     t = _golden_trace()
     cfg = HMSConfig(footprint=t.footprint, **kw)
-    old_s = set_forced_shards(1)
+    old_s = costmodel.set_forced_shards(1)
     old_t = costmodel.set_forced_tsplit(1)
     try:
         base = simulate(t, cfg).counters
     finally:
-        set_forced_shards(old_s)
+        costmodel.set_forced_shards(old_s)
         costmodel.set_forced_tsplit(old_t)
-    old_s = set_forced_shards(2)
+    old_s = costmodel.set_forced_shards(2)
     old_t = costmodel.set_forced_tsplit(4)
     old_r = tsplit.set_replay_prefix(16)
     try:
         got = simulate(t, cfg).counters
     finally:
-        set_forced_shards(old_s)
+        costmodel.set_forced_shards(old_s)
         costmodel.set_forced_tsplit(old_t)
         tsplit.set_replay_prefix(old_r)
     for k in _COUNTERS:

@@ -110,7 +110,7 @@ def _nbytes(args) -> int:
 
 
 def test_input_bytes_is_what_the_call_staged(spans, monkeypatch):
-    hms_calls = _capture(monkeypatch, sim_mod, "_batched_engine_for")
+    hms_calls = _capture(monkeypatch, sim_mod, "_engine_for")
     um_calls = _capture(monkeypatch, um_mod, "_engine_for")
     _study(make_trace("bfs_tu", n=N))
     (h,) = hms_calls
@@ -132,12 +132,12 @@ def test_engine_module_names_are_pinned(monkeypatch):
     """The device-trace readers find the engines by module name
     (``hms_scan_us_per_step``: ``jit_fn``, ``um_scan_us_per_step``:
     ``jit_counting``); a rename would silence them."""
-    batched = _capture(monkeypatch, sim_mod, "_batched_engine_for")
-    single = _capture(monkeypatch, sim_mod, "_engine_for")
+    hms_calls = _capture(monkeypatch, sim_mod, "_engine_for")
     um_calls = _capture(monkeypatch, um_mod, "_engine_for")
     trace = make_trace("bfs_tu", n=N)
     _study(trace)
     simulate(trace, HMSConfig(footprint=trace.footprint, ctc_fraction=0.5))
-    assert _module_name(*batched[0]) == "jit_fn"
-    assert _module_name(*single[0]) == "jit_fn"
+    many, single = hms_calls
+    assert _module_name(*many) == "jit_fn"
+    assert _module_name(*single) == "jit_fn"
     assert _module_name(*um_calls[0]) == "jit_counting"

@@ -24,9 +24,9 @@ import numpy as np
 import pytest
 
 from repro import obs, um
-from repro.core import HMSConfig, make_trace, simulate, simulate_many
+from repro.core import (HMSConfig, costmodel, make_trace, simulate,
+                        simulate_many)
 from repro.core import simulator as sim_mod
-from repro.core.simulator import set_max_shards
 from repro.core.traces import Trace
 
 
@@ -139,11 +139,11 @@ def test_counter_digest_stable_across_shard_counts():
     t = make_trace("bfs_tu", n=20_000)
     cfg = HMSConfig(footprint=t.footprint)
     auto = obs.counter_digest(simulate(t, cfg).counters)
-    old = set_max_shards(1)
+    old = costmodel.set_max_shards(1)
     try:
         seq = obs.counter_digest(simulate(t, cfg).counters)
     finally:
-        set_max_shards(old)
+        costmodel.set_max_shards(old)
     assert auto == seq
 
 

@@ -3,8 +3,8 @@
 The contracts under test:
 
   * the shared path (one ``_score_stream`` per distinct ``_score_key``,
-    then each config packed from it) gives every config the arrays
-    ``_request_stream`` gives it alone, array for array,
+    then each config packed from it) gives every config the arrays its
+    own score stream packs to, array for array,
   * ``_score_key`` holds exactly what ``_score_stream`` reads: a change of
     any other ``HMSConfig`` field leaves the score stream as it was, and a
     change of a field it reads moves the key,
@@ -73,7 +73,8 @@ def test_shared_streams_equal_per_config_streams(trace, shared, policy):
     for c, p, s in zip(cfgs, pres, streams):
         if c.policy != policy:
             continue
-        alone = sim_mod._request_stream(trace, c, p)
+        alone = sim_mod._pack_stream(trace, c, p,
+                                     sim_mod._score_stream(trace, c, p))
         assert set(s) == set(alone) == set(STREAM_KEYS)
         for k in STREAM_KEYS:
             assert s[k].dtype == alone[k].dtype, (c, k)

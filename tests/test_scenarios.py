@@ -12,9 +12,9 @@ import dataclasses
 import numpy as np
 import pytest
 
-from repro.core import HMSConfig, make_trace, simulate, simulate_many
-from repro.core.simulator import (_COUNTERS, set_forced_shards,
-                                  set_max_shards)
+from repro.core import (HMSConfig, costmodel, make_trace, simulate,
+                        simulate_many)
+from repro.core.simulator import _COUNTERS
 from repro.core.traces import WORKLOADS, split_weighted
 from repro.workloads import SCENARIOS, Phase, Scenario
 
@@ -212,16 +212,16 @@ def test_counter_exactness_at_1e6_requests():
     cfg = HMSConfig(footprint=t.footprint)
 
     auto = simulate(t, cfg)              # cost-model-selected shard count
-    old = set_forced_shards(8)
+    old = costmodel.set_forced_shards(8)
     try:
         sharded = simulate(t, cfg)
     finally:
-        set_forced_shards(old)
-    old_cap = set_max_shards(1)
+        costmodel.set_forced_shards(old)
+    old_cap = costmodel.set_max_shards(1)
     try:
         seq = simulate(t, cfg)
     finally:
-        set_max_shards(old_cap)
+        costmodel.set_max_shards(old_cap)
 
     for r in (auto, sharded):
         for k in _COUNTERS:

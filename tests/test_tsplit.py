@@ -277,12 +277,12 @@ def test_costmodel_keeps_sequential_when_wide():
 
 def test_engine_key_clamps_forced_t_to_depth():
     """Forcing T beyond the scan depth degrades gracefully (T <= depth)."""
-    from repro.core.simulator import _engine_key
+    from repro.core.simulator import group_engine_key
 
     t = _fallback_trace(21, n=40)
     cfg = HMSConfig(footprint=t.footprint)
     with forced(8, 16):
-        key = _engine_key(t, cfg)
+        key = group_engine_key(t, [cfg])
         assert key.t_segments <= key.depth
 
 
