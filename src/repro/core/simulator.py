@@ -52,8 +52,9 @@ split so a sweep costs one compile and one short device loop:
     trace into S state-disjoint shards and remaps slots / row groups to
     shard-local indices; the engine gathers the precomputed per-request
     stream into ``(S, depth)`` shard layout, ``vmap``s the lean scan over
-    shards (padded steps are gated no-ops), and scatters the decision flags
-    back to trace order for the unchanged counter reduction.  The device
+    shards (padded steps are gated no-ops; a single shard runs the scan
+    alone, with no shard axis), and scatters the decision flags back to
+    trace order for the unchanged counter reduction.  The device
     loop shrinks from N sequential steps to max-shard-depth (~N/S) steps,
     exactly — parity with the sequential formulation is bit-for-bit because
     every slot and CTC set still sees its original request subsequence in
@@ -570,6 +571,35 @@ def _engine_inputs(trace: Trace, cfg: HMSConfig, pre, rs,
 # The compiled engine: the lean, integer-only stateful scan.
 # ---------------------------------------------------------------------------
 
+def _vmap_unless_one(body, in_axes=0):
+    """``jax.vmap(body, in_axes)``, except that a mapped axis of size 1 (read
+    from the first argument's shape at trace time) is not vmapped: the body
+    runs alone on its one slice and the axis is restored on the outputs.
+
+    XLA keeps a vmapped size-1 axis inside the scan's loop: the carry and
+    the per-step stream slices keep a ``[.., 1, ..]`` dimension tiled
+    ``T(1,128)`` where the body alone tiles ``T(8,128)``, and the loop
+    gains broadcasts, bitcasts and index-tuple gathers and scatters.  On a
+    TPU v5e a size-1 lane axis cost a batch of one (4 shards) 6.09 against
+    4.63 us a step; with a size-1 shard axis, 12 lanes at one shard ran
+    8.21 us a step, and two of the fusions that build its ``[12,1,1]``
+    broadcasts took 0.88 us of that.  ``functools.wraps`` keeps the
+    body's name, so a jitted engine still compiles as module ``jit_fn``."""
+    vmapped = jax.vmap(body, in_axes=in_axes)
+    axes = in_axes if isinstance(in_axes, tuple) else None
+
+    @functools.wraps(body)
+    def fn(*args):
+        if jax.tree_util.tree_leaves(args[0])[0].shape[0] > 1:
+            return vmapped(*args)
+        one = [a if (axes and axes[i] is None)
+               else jax.tree_util.tree_map(lambda x: x[0], a)
+               for i, a in enumerate(args)]
+        return jax.tree_util.tree_map(lambda y: y[None], body(*one))
+
+    return fn
+
+
 def _make_engine(key: _EngineKey):
     policy = key.policy
     use_ctc = policy in _USES_CTC
@@ -596,6 +626,9 @@ def _make_engine(key: _EngineKey):
         # the per-request stream is gathered into (shards, depth) layout via
         # the shard plan's position matrix, each shard carries its own
         # cache/CTC slice, and padded steps (pos == n) are gated no-ops.
+        # One shard (S = 1) runs the scan alone, without a shard axis
+        # (``_vmap_unless_one``): a vmapped size-1 axis stays in the loop's
+        # carry and stream slices and slows every step.
         # The decision stream is packed into one int32 word per request
         # (and the CTC state into two words per way) to keep per-lane scan
         # work minimal — the loop is work-bound, not dispatch-bound, once
@@ -699,6 +732,7 @@ def _make_engine(key: _EngineKey):
                 (cf, tf), y = jax.lax.scan(step, (cache0, ctc0), sh_xs)
                 return (cf, tf), y
 
+            # S x T > 1 lanes: always vmapped
             (cache_f, ctc_f), y_sh = jax.vmap(shard_scan)(
                 scan_xs, jnp.asarray(carry[0]), jnp.asarray(carry[1]))
         else:
@@ -709,7 +743,7 @@ def _make_engine(key: _EngineKey):
                 _, y = jax.lax.scan(step, (cache, ctcst), sh_xs)
                 return y
 
-            y_sh = jax.vmap(shard_scan)(scan_xs)      # (lanes, L) int32
+            y_sh = _vmap_unless_one(shard_scan)(scan_xs)  # (lanes, L) int32
 
         # scatter the packed decision words back to trace order; padding
         # sentinels land in the dropped overflow slot n
@@ -868,23 +902,11 @@ def _engine_for(key: _EngineKey):
     # jit re-specializes per batch shape on its own, so the key needs no
     # width component.
     if key not in _ENGINE_CACHE:
-        base = _counting(key)
+        # per-config xs/params/carries; the replay flag is shared.  A batch
+        # of one runs the body alone (``_vmap_unless_one``).
         split = key.t_segments > 1
-        # per-config xs/params/carries; the replay flag is shared
-        vmapped = jax.vmap(base, in_axes=(0, 0, 0, None) if split else 0)
-        batched = 3 if split else 2
-
-        def fn(*args):
-            if args[0]["pos"].shape[0] > 1:
-                return vmapped(*args)
-            # a batch of one runs the body unbatched: vmap would keep its
-            # size-1 axis in the scan's loop (6 more fusions and 3 more
-            # copies a step; 6.09 against 4.63 us a step on a TPU v5e)
-            one = jax.tree_util.tree_map(lambda a: a[0], args[:batched])
-            out = base(*one, *args[batched:])
-            return jax.tree_util.tree_map(lambda a: a[None], out)
-
-        _ENGINE_CACHE[key] = jax.jit(fn)
+        _ENGINE_CACHE[key] = jax.jit(_vmap_unless_one(
+            _counting(key), in_axes=(0, 0, 0, None) if split else 0))
     return _ENGINE_CACHE[key]
 
 

@@ -161,6 +161,80 @@ def test_batch_of_one_scans_without_a_lane_axis(t_seg):
     assert alone and width1 == alone
 
 
+def _engine_loops(t, cfgs, shards):
+    """The ``while`` tuples of the lowered engine at a forced (S, T=1)."""
+    import jax
+
+    from repro.core import simulator as sim
+    from repro.core.traces import preprocess
+
+    old_s = costmodel.set_forced_shards(shards)
+    old_t = costmodel.set_forced_tsplit(1)
+    try:
+        key = group_engine_key(t, cfgs)
+    finally:
+        costmodel.set_forced_shards(old_s)
+        costmodel.set_forced_tsplit(old_t)
+    assert (key.shards, key.t_segments) == (shards, 1)
+    lanes = []
+    with jax.enable_x64(True):
+        for cfg in cfgs:
+            pre = preprocess(t, cfg)
+            rs = sim._pack_stream(t, cfg, pre, sim._score_stream(t, cfg, pre))
+            lanes.append((sim._engine_inputs(t, cfg, pre, rs, key),
+                          sim._runtime_params(cfg,
+                                              sim._local_sets(t, cfg, key))))
+        args = jax.tree_util.tree_map(lambda *x: np.stack(x), *lanes)
+        text = sim._engine_for(key).lower(*args).as_text(dialect="hlo")
+    return re.findall(r"= (\(.*?\)) while\(", text)
+
+
+@pytest.mark.parametrize("shards", [1, 2], ids=["S1", "S2"])
+def test_single_shard_scans_without_a_shard_axis(shards):
+    """A 3-lane call at one shard loops over (lanes, ...) state and streams:
+    no size-1 shard axis in the scan's carry.  At two shards the shard
+    axis stays."""
+    t = _aliasing_trace()
+    cfgs = [HMSConfig(footprint=t.footprint, scm_mode=m).validate()
+            for m in ("slc", "mlc", "tlc")]
+    loops = _engine_loops(t, cfgs, shards)
+    assert loops
+    if shards == 1:
+        assert not any("[3,1," in w for w in loops), loops
+    else:
+        assert all("[3,2," in w for w in loops), loops
+
+
+@pytest.mark.parametrize("width", [1, 3], ids=["w1", "w3"])
+@pytest.mark.parametrize(
+    "kw", GOLDEN_CONFIGS,
+    ids=["hms", "tad", "no_bypass", "no_2nd", "bear", "mccache",
+         "redcache", "no_ctc"])
+def test_single_shard_parity_vs_reference(kw, width):
+    """At a forced single shard (S = 1, T = 1), the scan run without a shard
+    axis reproduces the seed scan engine for every golden policy, alone
+    and in a batch of three."""
+    t = _golden_trace()
+    cfg = HMSConfig(footprint=t.footprint, **kw)
+    cfgs = [cfg] if width == 1 else [
+        dataclasses.replace(cfg, scm_mode=m) for m in ("slc", "mlc", "tlc")]
+    old_s = costmodel.set_forced_shards(1)
+    old_t = costmodel.set_forced_tsplit(1)
+    try:
+        key = group_engine_key(t, cfgs)
+        assert (key.shards, key.t_segments) == (1, 1)
+        got = simulate_many(t, cfgs)
+    finally:
+        costmodel.set_forced_shards(old_s)
+        costmodel.set_forced_tsplit(old_t)
+    for c, r in zip(cfgs, got):
+        ref = reference_counters(t, c)
+        for k in _COUNTERS:
+            np.testing.assert_array_equal(
+                r.counters[k], ref[k],
+                err_msg=f"counter {k} diverged for {kw}, {c.scm_mode}")
+
+
 def test_simulate_many_matches_sequential():
     """Batched vmap execution reproduces per-config sequential counters."""
     t = _golden_trace()
